@@ -1,2 +1,1 @@
 """repro: TT-decomposition LLM compression on a JAX/Pallas stack."""
-from . import _compat  # noqa: F401  (installs jax 0.4.x mesh-API shims on import)

@@ -4,8 +4,10 @@ The paper's GVSA dataflow works because tile shapes, DSP sharing and
 schedules obey statically checkable design rules; the Pallas analog has the
 same flavor of invariants, checked here to the extent the AST permits:
 
-* **PAL001** — every ``pallas_call`` declares an explicit ``grid=``
-  (implicit grids hide the tiling contract).
+* **PAL001** — every ``pallas_call`` declares an explicit ``grid=`` or a
+  ``grid_spec=`` (e.g. ``pltpu.PrefetchScalarGridSpec``, which carries the
+  grid); implicit grids hide the tiling contract.  The literal-grid checks
+  below read ``grid=`` only.
 * **PAL002** — when the grid is a literal tuple, every ``BlockSpec``
   index-map lambda must take exactly ``len(grid)`` arguments (an arity
   mismatch is a guaranteed lowering failure, caught here without tracing).
@@ -185,6 +187,8 @@ def check(index, config):
             if not (isinstance(node, ast.Call) and _is_pallas_call(node)):
                 continue
             grid = _kw(node, "grid")
+            if grid is None and _kw(node, "grid_spec") is not None:
+                continue
             if grid is None:
                 yield Finding(
                     "PAL001", FAMILY, sf.rel, node.lineno, node.col_offset,

@@ -18,10 +18,34 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from .._compat import active_mesh, manual_axis_names
-
 #: mesh axes a batch dimension may shard over, outermost first.
 BATCH = ("pod", "data")
+
+
+def active_mesh():
+    """The mesh made current by ``with mesh:`` or ``jax.set_mesh``, else None."""
+    try:  # classic pjit resource env (`with mesh:`)
+        from jax._src import mesh as mesh_lib
+
+        m = mesh_lib.thread_resources.env.physical_mesh
+        if m is not None and not m.empty:
+            return m
+    except Exception:  # noqa: BLE001 - internal layout differs across versions
+        pass
+    m = jax.sharding.get_abstract_mesh()
+    if m is not None and getattr(m, "axis_names", ()):
+        return m
+    return None
+
+
+def manual_axis_names() -> set:
+    """Axis names currently bound as manual/mapped (inside shard_map et al.)."""
+    try:
+        from jax._src.core import get_axis_env
+
+        return set(get_axis_env().axis_sizes)
+    except Exception:  # noqa: BLE001
+        return set()
 
 
 def current_abstract_mesh():

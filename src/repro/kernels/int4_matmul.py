@@ -60,7 +60,7 @@ def int4_matmul_pallas(x: jax.Array, qweight: jax.Array, scales: jax.Array, *,
                        bias: jax.Array | None = None,
                        residual: jax.Array | None = None,
                        activation: str | None = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """y = act(x @ dequant(qweight)^T [* scale] [+ bias]) [+ residual].
 
     x: (B, K) -> (B, M).  The epilogue operands mirror the TT kernel's

@@ -9,19 +9,15 @@ GQA head grouping — into a single pass, so decode never materializes a
 gathered (B, S, Hkv, Dh) context in HBM the way the pure-JAX reference
 (``kernels/ref.py::paged_attention``) does.
 
-Grid: one program per sequence.  The program walks only the blocks its
-sequence actually occupies (``fori_loop`` with a data-dependent trip count),
-streaming one (block_size, Hkv, Dh) K/V tile at a time through the flash
-online-softmax recurrence; the running (m, l, acc) state is O(heads) and the
-ragged last block / empty sequence cases fall out of the position mask.
-
-The K/V pools are handed to the kernel whole (index-mapped to block (0,…))
-and sliced per block id with ``pl.ds`` — correct under the interpreter and
-for Mosaic as long as the pool fits VMEM.  A production TPU build would
-instead prefetch the block table as a scalar argument
-(``pltpu.PrefetchScalarGridSpec``) and let the BlockSpec index_map DMA one
-block per grid step from HBM; that variant changes only this file, not the
-dispatch contract.
+Grid: ``(sequence, logical block)``.  The block table and the query
+positions are scalar-prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``),
+so the K/V BlockSpec index_map looks up the physical block id and the
+pipeline DMAs exactly one (block_size, Hkv, Dh) K/V tile from HBM per grid
+step — the pool itself never has to fit VMEM.  Steps past a sequence's last
+occupied block re-map to that block (the pipeline skips the repeated fetch)
+and skip their compute; the running (m, l, acc) online-softmax state lives in
+VMEM scratch across a sequence's steps, and the ragged last block / empty
+sequence cases fall out of the position mask.
 """
 from __future__ import annotations
 
@@ -31,53 +27,68 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, bt_ref, qpos_ref, k_ref, v_ref, *refs,
-            block_size: int, n_kv_heads: int, sm_scale: float,
-            quantized: bool, out_dtype):
-    out_ref = refs[-1]
-    ks_ref, vs_ref = (refs[0], refs[1]) if quantized else (None, None)
-    q = q_ref[0]  # (H, Dh)
-    h, dh = q.shape
-    g = h // n_kv_heads
-    qh = q.reshape(n_kv_heads, g, dh).astype(jnp.float32) * sm_scale
-    qpos = qpos_ref[0]  # scalar int32; -1 = inactive sequence
-    n_blocks = (jnp.maximum(qpos + 1, 0) + block_size - 1) // block_size
+def _n_blocks(qpos, block_size: int):
+    """Blocks a sequence whose newest token sits at ``qpos`` occupies."""
+    return (jnp.maximum(qpos + 1, 0) + block_size - 1) // block_size
 
-    def body(j, carry):
-        m, l, acc = carry
-        blk = bt_ref[0, j]
-        kb = k_ref[pl.ds(blk, 1)][0].astype(jnp.float32)  # (BS, Hkv, Dh)
-        vb = v_ref[pl.ds(blk, 1)][0].astype(jnp.float32)
-        if quantized:
-            kb = kb * ks_ref[pl.ds(blk, 1)][0][..., None]
-            vb = vb * vs_ref[pl.ds(blk, 1)][0][..., None]
-        s = jnp.einsum("hgd,khd->hgk", qh, kb)  # (Hkv, G, BS)
-        kpos = j * block_size + jnp.arange(block_size, dtype=jnp.int32)
-        valid = kpos <= qpos  # causal + ragged-last-block mask
-        s = jnp.where(valid[None, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None]) * valid[None, None, :]
-        l_new = l * corr + p.sum(-1)
-        pv = jnp.einsum("hgk,khd->hgd", p, vb)
-        acc_new = acc * corr[..., None] + pv
-        return m_new, l_new, acc_new
 
-    m0 = jnp.full((n_kv_heads, g), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv_heads, g), jnp.float32)
-    a0 = jnp.zeros((n_kv_heads, g, dh), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, a0))
-    out = jnp.where(l[..., None] > 0, acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
-    out_ref[0] = out.reshape(h, dh).astype(out_dtype)
+def _kernel(bt_ref, qpos_ref, q_ref, k_ref, v_ref, *refs, block_size: int,
+            n_kv_heads: int, sm_scale: float, quantized: bool):
+    del bt_ref  # consumed by the index_maps
+    if quantized:
+        ks_ref, vs_ref, out_ref, m_sc, l_sc, acc_sc = refs
+    else:
+        out_ref, m_sc, l_sc, acc_sc = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+    qpos = qpos_ref[i]  # -1 = inactive sequence
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j < _n_blocks(qpos, block_size))
+    def _step():
+        q = q_ref[0].astype(jnp.float32) * sm_scale  # (H, Dh)
+        g = q.shape[0] // n_kv_heads
+        kpos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        valid = kpos <= qpos  # causal + ragged-last-block mask, (1, BS)
+        for h in range(n_kv_heads):
+            kb = k_ref[0, :, h, :].astype(jnp.float32)  # (BS, Dh)
+            vb = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                kb = kb * ks_ref[0, :, h:h + 1]
+                vb = vb * vs_ref[0, :, h:h + 1]
+            rows = slice(h * g, (h + 1) * g)
+            s = jax.lax.dot_general(q[rows], kb, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s, NEG_INF)  # (G, BS)
+            m_prev, l_prev = m_sc[rows], l_sc[rows]  # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new) * valid
+            l_sc[rows] = l_prev * corr + p.sum(-1, keepdims=True)
+            acc_sc[rows] = acc_sc[rows] * corr + jnp.dot(
+                p, vb, preferred_element_type=jnp.float32)
+            m_sc[rows] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        l = l_sc[...]
+        out = jnp.where(l > 0, acc_sc[...] / jnp.maximum(l, 1e-30), 0.0)
+        out_ref[0] = out.astype(out_ref.dtype)
 
 
 def paged_attention_pallas(q: jax.Array, cache: dict, block_tables: jax.Array,
                            qpos: jax.Array, *, sm_scale: float | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Decode attention through a block table; one query token per sequence.
 
     q: (B, H, Dh); cache: ``{"k","v": (NB, BS, Hkv, Dh)}`` plus
@@ -86,38 +97,51 @@ def paged_attention_pallas(q: jax.Array, cache: dict, block_tables: jax.Array,
     new token (its K/V already written), ``-1`` for inactive rows (output
     zeros).  Returns (B, H, Dh) in ``q.dtype``.
 
-    ``interpret`` defaults True like the other ``*_pallas`` kernels (this
-    repo's tests run on CPU); production callers go through
-    ``kernels.dispatch.paged_attention``, which sets it from the backend
-    policy (``pallas`` → compiled via Mosaic).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (the CPU
+    test path); serving goes through ``kernels.dispatch.paged_attention``,
+    which sets it from the backend policy (``pallas`` → compiled via Mosaic).
     """
     b, h, dh = q.shape
-    nb, bs, hkv, _ = cache["k"].shape
+    _, bs, hkv, _ = cache["k"].shape
     w = block_tables.shape[1]
     quantized = "k_scale" in cache
     sm_scale = sm_scale or (1.0 / math.sqrt(dh))
 
+    def kv_block(i, j, bt, qp):
+        # past the last occupied block: stay on it (no new DMA, no compute)
+        last = jnp.maximum(_n_blocks(qp[i], bs) - 1, 0)
+        return bt[i * w + jnp.minimum(j, last)], 0, 0, 0
+
+    def scale_block(i, j, bt, qp):
+        return kv_block(i, j, bt, qp)[:3]
+
     in_specs = [
-        pl.BlockSpec((1, h, dh), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, w), lambda i: (i, 0)),
-        pl.BlockSpec((1,), lambda i: (i,)),
-        pl.BlockSpec((nb, bs, hkv, dh), lambda i: (0, 0, 0, 0)),
-        pl.BlockSpec((nb, bs, hkv, dh), lambda i: (0, 0, 0, 0)),
+        pl.BlockSpec((1, h, dh), lambda i, j, bt, qp: (i, 0, 0)),
+        pl.BlockSpec((1, bs, hkv, dh), kv_block),
+        pl.BlockSpec((1, bs, hkv, dh), kv_block),
     ]
-    args = [q, block_tables.astype(jnp.int32), qpos.astype(jnp.int32),
-            cache["k"], cache["v"]]
+    args = [q, cache["k"], cache["v"]]
     if quantized:
         for nm in ("k_scale", "v_scale"):
-            in_specs.append(pl.BlockSpec((nb, bs, hkv), lambda i: (0, 0, 0)))
+            in_specs.append(pl.BlockSpec((1, bs, hkv), scale_block))
             args.append(cache[nm].astype(jnp.float32))
 
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, w),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, h, dh), lambda i, j, bt, qp: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, dh), jnp.float32)],
+    )
     return pl.pallas_call(
         functools.partial(_kernel, block_size=bs, n_kv_heads=hkv,
-                          sm_scale=sm_scale, quantized=quantized,
-                          out_dtype=q.dtype),
-        grid=(b,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, dh), lambda i: (i, 0, 0)),
+                          sm_scale=sm_scale, quantized=quantized),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(block_tables.astype(jnp.int32).reshape(-1), qpos.astype(jnp.int32),
+      *args)

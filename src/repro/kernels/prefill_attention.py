@@ -7,29 +7,32 @@ whole ``(B, W*BS, Hkv, Dh)`` f32 gathered context in HBM and computed dense
 ``(Sq × K)`` scores including idle rows.  This kernel closes that gap — the
 last fork between "kernel-accelerated decode" and "oracle-math prefill".
 
-Grid: ``(seq, q-tile)`` — one program per (sequence, tile of query tokens).
-Each program streams K/V tiles through the flash online-softmax recurrence,
+One (sequence, tile of query tokens) streams K/V tiles through the flash
+online-softmax recurrence,
 with GQA head grouping and causal + sliding-window masking driven by
 per-sequence absolute positions (``-1`` = padding → zero output).  Two cache
 layouts share the kernel body:
 
 * **paged** — K/V live in shared block pools addressed through a per-sequence
   block table; K positions are implicit (gathered index *i* holds absolute
-  position *i*), tiles are the ``block_size``-wide blocks, and the loop trip
-  count is the tile's max query position rounded up to blocks, so a program
-  never reads beyond the blocks its sequence actually occupies (all-idle
-  tiles run zero iterations).  int8 pools dequantize per-(block-slot, head)
+  position *i*), tiles are the ``block_size``-wide blocks, and the visible
+  block count is the tile's max query position rounded up to blocks, so a
+  tile never reads beyond the blocks its sequence actually occupies
+  (all-idle tiles compute nothing).  int8 pools dequantize per-(block-slot, head)
   scales in-tile, fused with the score matmul.
 * **ring** — K/V are per-slot rings with an explicit ``kpos`` operand
   (``-1`` = empty entry); tiles stream over the ring width, and the mask is
   position-driven (causal, ``kpos >= 0``, sliding window), so SWA families
   (mixtral, griffin's attention layers) prefill through the same kernel.
 
-Like ``kernels/paged_attention.py``, the pools/rings are handed to the kernel
-whole and sliced per tile — correct under the interpreter and for Mosaic
-while they fit VMEM; a production TPU build would prefetch the block table as
-a scalar argument (``pltpu.PrefetchScalarGridSpec``) and DMA one tile per
-grid step from HBM, changing only this file, not the dispatch contract.
+Grid: ``(seq, q-tile, kv-tile)``.  Like ``kernels/paged_attention.py``, the
+paged layout scalar-prefetches the block table (and each q tile's visible
+block count) into SMEM, so the K/V BlockSpec index_map resolves the physical
+block id and the pipeline DMAs one (block_size, Hkv, Dh) tile from HBM per
+grid step; steps past a tile's last visible block re-map to it (no new
+fetch) and skip their compute.  The ring layout streams its per-slot ring
+through the same grid with a plain index_map.  The online-softmax state for
+one (seq, q-tile) lives in VMEM scratch across its kv-tile steps.
 """
 from __future__ import annotations
 
@@ -39,80 +42,80 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _kernel(q_ref, qpos_ref, *refs, paged: bool, kv_tile: int, n_kv_tiles: int,
-            n_kv_heads: int, window: int, sm_scale: float, quantized: bool,
-            out_dtype):
-    out_ref = refs[-1]
-    if paged:
-        bt_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        ks_ref, vs_ref = (refs[3], refs[4]) if quantized else (None, None)
+def _kernel(*refs, paged: bool, kv_tile: int, n_kv_heads: int, window: int,
+            sm_scale: float, quantized: bool):
+    if paged:  # the block table itself is read by the index_maps
+        _, nblk_ref, q_ref, qpos_ref, k_ref, v_ref, *refs = refs
         kpos_ref = None
     else:
-        kpos_ref, k_ref, v_ref = refs[0], refs[1], refs[2]
-        ks_ref, vs_ref = (refs[3], refs[4]) if quantized else (None, None)
-        bt_ref = None
-    q = q_ref[0]  # (QT, H, Dh)
-    qt, h, dh = q.shape
-    g = h // n_kv_heads
-    qh = q.reshape(qt, n_kv_heads, g, dh).astype(jnp.float32) * sm_scale
-    qpos = qpos_ref[0]  # (QT,) int32; -1 = padding row
-    if paged:
-        # walk only the blocks this tile's queries can see (0 when all-idle)
-        qmax = jnp.max(qpos)
-        n_tiles = (jnp.maximum(qmax + 1, 0) + kv_tile - 1) // kv_tile
-        ring_k = ring_v = ring_pos = None
-    else:
-        n_tiles = n_kv_tiles  # static: ring width is fixed per call
-        ring_k = k_ref[0]     # (WR, Hkv, Dh) — already VMEM-resident
-        ring_v = v_ref[0]
-        ring_pos = kpos_ref[0]
+        q_ref, qpos_ref, kpos_ref, k_ref, v_ref, *refs = refs
+    if quantized:
+        ks_ref, vs_ref, *refs = refs
+    out_ref, m_sc, l_sc, acc_sc = refs
+    i, jq, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
-    def body(j, carry):
-        m, l, acc = carry
+    @pl.when(t == 0)
+    def _init():
+        m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def _step():
+        qt, h, dh = q_ref.shape[1:]
+        g = h // n_kv_heads
+        # rows are (query, head-in-group); each row's absolute position
+        qrow = jnp.broadcast_to(qpos_ref[0][:, None, :], (qt, g, 1)
+                                ).reshape(qt * g, 1)
         if paged:
-            blk = bt_ref[0, j]
-            kb = k_ref[pl.ds(blk, 1)][0].astype(jnp.float32)  # (KT, Hkv, Dh)
-            vb = v_ref[pl.ds(blk, 1)][0].astype(jnp.float32)
-            if quantized:
-                kb = kb * ks_ref[pl.ds(blk, 1)][0][..., None]
-                vb = vb * vs_ref[pl.ds(blk, 1)][0][..., None]
-            kpos = j * kv_tile + jnp.arange(kv_tile, dtype=jnp.int32)
-            valid = kpos[None, :] <= qpos[:, None]  # causal + ragged block
+            kpos = t * kv_tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, kv_tile), 1)
+            valid = kpos <= qrow  # causal + ragged block
         else:
-            kb = jax.lax.dynamic_slice_in_dim(ring_k, j * kv_tile, kv_tile
-                                              ).astype(jnp.float32)
-            vb = jax.lax.dynamic_slice_in_dim(ring_v, j * kv_tile, kv_tile
-                                              ).astype(jnp.float32)
-            if quantized:
-                kb = kb * jax.lax.dynamic_slice_in_dim(
-                    ks_ref[0], j * kv_tile, kv_tile)[..., None]
-                vb = vb * jax.lax.dynamic_slice_in_dim(
-                    vs_ref[0], j * kv_tile, kv_tile)[..., None]
-            kpos = jax.lax.dynamic_slice_in_dim(ring_pos, j * kv_tile, kv_tile)
-            valid = (kpos[None, :] >= 0) & (kpos[None, :] <= qpos[:, None])
-        valid &= qpos[:, None] >= 0
+            kpos = kpos_ref[0]  # (1, KT); -1 = empty ring entry
+            valid = (kpos >= 0) & (kpos <= qrow)
+        valid &= qrow >= 0
         if window > 0:
-            valid &= qpos[:, None] - kpos[None, :] < window
-        s = jnp.einsum("qhgd,khd->hgqk", qh, kb)  # (Hkv, G, QT, KT)
-        s = jnp.where(valid[None, None], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None]) * valid[None, None]
-        l_new = l * corr + p.sum(-1)
-        pv = jnp.einsum("hgqk,khd->hgqd", p, vb)
-        acc_new = acc * corr[..., None] + pv
-        return m_new, l_new, acc_new
+            valid &= qrow - kpos < window
+        for hk in range(n_kv_heads):
+            heads = slice(hk * g, (hk + 1) * g)
+            qh = (q_ref[0, :, heads, :].astype(jnp.float32) * sm_scale
+                  ).reshape(qt * g, dh)
+            kb = k_ref[0, :, hk, :].astype(jnp.float32)  # (KT, Dh)
+            vb = v_ref[0, :, hk, :].astype(jnp.float32)
+            if quantized:
+                kb = kb * ks_ref[0, :, hk:hk + 1]
+                vb = vb * vs_ref[0, :, hk:hk + 1]
+            s = jax.lax.dot_general(qh, kb, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s, NEG_INF)  # (QT*G, KT)
+            m_prev, l_prev = m_sc[hk], l_sc[hk]  # (QT*G, 1)
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new) * valid
+            l_sc[hk] = l_prev * corr + p.sum(-1, keepdims=True)
+            acc_sc[hk] = acc_sc[hk] * corr + jnp.dot(
+                p, vb, preferred_element_type=jnp.float32)
+            m_sc[hk] = m_new
 
-    m0 = jnp.full((n_kv_heads, g, qt), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((n_kv_heads, g, qt), jnp.float32)
-    a0 = jnp.zeros((n_kv_heads, g, qt, dh), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, n_tiles, body, (m0, l0, a0))
-    out = jnp.where(l[..., None] > 0, acc / jnp.maximum(l, 1e-30)[..., None], 0.0)
-    out_ref[0] = out.transpose(2, 0, 1, 3).reshape(qt, h, dh).astype(out_dtype)
+    if paged:
+        pl.when(t < nblk_ref[i * pl.num_programs(1) + jq])(_step)
+    else:
+        _step()
+
+    @pl.when(t == pl.num_programs(2) - 1)
+    def _finish():
+        qt, h, dh = q_ref.shape[1:]
+        g = h // n_kv_heads
+        for hk in range(n_kv_heads):
+            l = l_sc[hk]
+            out = jnp.where(l > 0, acc_sc[hk] / jnp.maximum(l, 1e-30), 0.0)
+            out_ref[0, :, hk * g:(hk + 1) * g, :] = out.reshape(
+                qt, g, dh).astype(out_ref.dtype)
 
 
 def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
@@ -125,7 +128,7 @@ def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
                              k_scale: jax.Array | None = None,
                              v_scale: jax.Array | None = None,
                              q_tile: int = 64, kv_tile: int = 128,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool) -> jax.Array:
     """Chunked-prefill attention over a paged pool or per-slot rings.
 
     q: (B, Sq, H, Dh); qpos: (B, Sq) int32 absolute query positions (``-1`` =
@@ -140,8 +143,8 @@ def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
 
     The chunk's own K/V must already be written (write-then-attend, as both
     ``paged_kv_update`` and ``ring_kv_update`` guarantee).  Returns
-    (B, Sq, H, Dh) in ``q.dtype``.  ``interpret`` defaults True like the
-    other ``*_pallas`` kernels; production callers go through
+    (B, Sq, H, Dh) in ``q.dtype``.  ``interpret=True`` runs the kernel in
+    the Pallas interpreter (the CPU test path); serving goes through
     ``kernels.dispatch.prefill_attention``.
     """
     paged = cache is not None
@@ -152,30 +155,34 @@ def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
     if pad_q:
         q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0)))
         qpos = jnp.pad(qpos, ((0, 0), (0, pad_q)), constant_values=-1)
+    qpos = qpos.astype(jnp.int32)
     nqt = q.shape[1] // qt
-    grid = (b, nqt)
-
-    in_specs = [
-        pl.BlockSpec((1, qt, h, dh), lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec((1, qt), lambda i, j: (i, j)),
-    ]
-    args = [q, qpos.astype(jnp.int32)]
 
     if paged:
-        nb, bs, hkv, _ = cache["k"].shape
+        _, bs, hkv, _ = cache["k"].shape
         w = block_tables.shape[1]
         quantized = "k_scale" in cache
-        kv_t, n_kv_tiles = bs, 0  # trip count is data-dependent (block walk)
-        in_specs += [
-            pl.BlockSpec((1, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((nb, bs, hkv, dh), lambda i, j: (0, 0, 0, 0)),
-            pl.BlockSpec((nb, bs, hkv, dh), lambda i, j: (0, 0, 0, 0)),
-        ]
-        args += [block_tables.astype(jnp.int32), cache["k"], cache["v"]]
+        kv_t, n_t = bs, w
+        # blocks each q tile can see: its max position rounded up (0 if idle)
+        qmax = qpos.reshape(b, nqt, qt).max(-1)
+        nblk = (jnp.maximum(qmax + 1, 0) + bs - 1) // bs
+        scalars = [block_tables.astype(jnp.int32).reshape(-1),
+                   nblk.reshape(-1).astype(jnp.int32)]
+
+        def kv_block(i, jq, t, bt, nb):
+            last = jnp.maximum(nb[i * nqt + jq] - 1, 0)
+            return bt[i * w + jnp.minimum(t, last)], 0, 0, 0
+
+        def q_map(f):
+            return lambda i, jq, t, bt, nb: f(i, jq, t)
+
+        kv_specs = [pl.BlockSpec((1, bs, hkv, dh), kv_block)] * 2
+        kv_args = [cache["k"], cache["v"]]
         if quantized:
-            for nm in ("k_scale", "v_scale"):
-                in_specs.append(pl.BlockSpec((nb, bs, hkv), lambda i, j: (0, 0, 0)))
-                args.append(cache[nm].astype(jnp.float32))
+            kv_specs += [pl.BlockSpec((1, bs, hkv),
+                                      lambda *a: kv_block(*a)[:3])] * 2
+            kv_args += [cache["k_scale"].astype(jnp.float32),
+                        cache["v_scale"].astype(jnp.float32)]
     else:
         if k is None or v is None or kpos is None:
             raise ValueError("ring layout needs k, v and kpos")
@@ -190,30 +197,42 @@ def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
             if quantized:
                 k_scale = jnp.pad(k_scale, ((0, 0), (0, pad_k), (0, 0)))
                 v_scale = jnp.pad(v_scale, ((0, 0), (0, pad_k), (0, 0)))
-        n_kv_tiles = k.shape[1] // kv_t
-        wr = k.shape[1]
-        in_specs += [
-            pl.BlockSpec((1, wr), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, wr, hkv, dh), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((1, wr, hkv, dh), lambda i, j: (i, 0, 0, 0)),
-        ]
-        args += [kpos.astype(jnp.int32), k, v]
-        if quantized:
-            in_specs += [
-                pl.BlockSpec((1, wr, hkv), lambda i, j: (i, 0, 0)),
-                pl.BlockSpec((1, wr, hkv), lambda i, j: (i, 0, 0)),
-            ]
-            args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        n_t = k.shape[1] // kv_t
+        scalars = []
 
+        def q_map(f):
+            return f
+
+        kv_specs = [pl.BlockSpec((1, 1, kv_t), lambda i, jq, t: (i, 0, t))]
+        kv_specs += [pl.BlockSpec((1, kv_t, hkv, dh),
+                                  lambda i, jq, t: (i, t, 0, 0))] * 2
+        kv_args = [kpos.astype(jnp.int32)[:, None, :], k, v]
+        if quantized:
+            kv_specs += [pl.BlockSpec((1, kv_t, hkv),
+                                      lambda i, jq, t: (i, t, 0))] * 2
+            kv_args += [k_scale.astype(jnp.float32),
+                        v_scale.astype(jnp.float32)]
+
+    g = h // hkv
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(b, nqt, n_t),
+        in_specs=[pl.BlockSpec((1, qt, h, dh), q_map(lambda i, jq, t: (i, jq, 0, 0))),
+                  pl.BlockSpec((1, qt, 1), q_map(lambda i, jq, t: (i, jq, 0)))]
+        + kv_specs,
+        out_specs=pl.BlockSpec((1, qt, h, dh), q_map(lambda i, jq, t: (i, jq, 0, 0))),
+        scratch_shapes=[pltpu.VMEM((hkv, qt * g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, qt * g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, qt * g, dh), jnp.float32)],
+    )
     out = pl.pallas_call(
-        functools.partial(_kernel, paged=paged, kv_tile=kv_t,
-                          n_kv_tiles=n_kv_tiles, n_kv_heads=hkv,
+        functools.partial(_kernel, paged=paged, kv_tile=kv_t, n_kv_heads=hkv,
                           window=window, sm_scale=sm_scale,
-                          quantized=quantized, out_dtype=q.dtype),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, qt, h, dh), lambda i, j: (i, j, 0, 0)),
+                          quantized=quantized),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*args)
+    )(*scalars, q, qpos[:, :, None], *kv_args)
     return out[:, :sq] if pad_q else out

@@ -86,7 +86,7 @@ def _decode_kernel(la_ref, gx_ref, h0_ref, pos_ref, h_ref, hlast_ref, *,
 
 def rglru_scan_pallas(log_a, gx, h0, pos=None, *, scan_dtype=None,
                       token_tile: int = 16, width_tile: int = 128,
-                      interpret: bool = True):
+                      interpret: bool):
     """Fused RG-LRU scan.  Same contract as ``kernels.ref.rglru_scan``:
     log_a/gx (B,S,W), h0 (B,W) f32, pos (B,S) int32 (``-1`` = padding) or
     None (all steps real).  Returns (h (B,S,W) scan_dtype, h_last (B,W) f32).

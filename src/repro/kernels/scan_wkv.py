@@ -100,7 +100,7 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, pos_ref, u_ref, s0_ref, *refs,
 
 
 def wkv_scan_pallas(r, k, v, w, u, state0, pos=None, *, state_scale=None,
-                    chunk: int = WKV_CHUNK, interpret: bool = True):
+                    chunk: int = WKV_CHUNK, interpret: bool):
     """Fused wkv scan.  Same contract as ``kernels.ref.wkv_scan``:
     r/k/v/w (B,S,H,hd), u (H,hd), state0 (B,H,hd,hd) f32 — or int8 with
     ``state_scale`` (B,H) f32 — pos (B,S) int32 (``-1`` = padding) or None.

@@ -78,7 +78,7 @@ def _kernel(ids_ref, *refs, spec: TTSpec, block_t: int):
 
 def tt_embed_pallas(ids: jax.Array, cores: list[jax.Array], spec: TTSpec, *,
                     block_t: int | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """ids (T,) int32 -> (T, D) f32 rows of the TT-described (V, D) table."""
     (t,) = ids.shape
     bt = block_t or pick_block_t(spec, max(t, 8))

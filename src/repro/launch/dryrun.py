@@ -226,8 +226,6 @@ def lower_cell(arch: str, cell: ShapeCell, mesh, *, baseline: bool = False,
 def analyze(lowered, compiled, mesh) -> dict:
     n_dev = mesh.devices.size
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     mem = compiled.memory_analysis()
     mem_d = {}
     for f in ("argument_size_in_bytes", "output_size_in_bytes",

@@ -59,8 +59,10 @@ def test_cli_exit_codes(fixture, expected, capsys):
     assert rc == expected, capsys.readouterr().out
 
 
-def test_good_fixture_is_clean():
-    assert codes_of(FIX / "good_host_sync.py") == set()
+@pytest.mark.parametrize("fixture", ["good_host_sync.py",
+                                     "good_pallas_grid_spec.py"])
+def test_good_fixture_is_clean(fixture):
+    assert codes_of(FIX / fixture) == set()
 
 
 def test_inline_allow_suppresses_and_is_counted():

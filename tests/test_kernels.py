@@ -411,12 +411,14 @@ def test_prefill_attention_all_idle_rows():
     q, cache, bt, _ = _prefill_paged_case(5, block_size=4, ctx_lens=(8, 3),
                                           chunk=4)
     qpos = jnp.full((2, 4), -1, jnp.int32)
-    for y in (prefill_attention_pallas(q, qpos, cache=cache, block_tables=bt),
+    for y in (prefill_attention_pallas(q, qpos, cache=cache, block_tables=bt,
+                                       interpret=True),
               ref.paged_attention(q, cache, bt, qpos)):
         assert float(jnp.max(jnp.abs(y))) == 0.0
     q, k, v, kpos, _ = _prefill_ring_case(6, ring_width=8, ctx_lens=(6, 2),
                                           chunk=4)
-    for y in (prefill_attention_pallas(q, qpos, k=k, v=v, kpos=kpos),
+    for y in (prefill_attention_pallas(q, qpos, k=k, v=v, kpos=kpos,
+                                       interpret=True),
               ref.ring_attention(q, k, v, qpos, kpos)):
         assert float(jnp.max(jnp.abs(y))) == 0.0
 
