@@ -128,8 +128,9 @@ def run(report=print, *, families=FAMILIES, n_requests: int = 12,
             row = traffic_row(
                 result=result, registry=obs.registry, family=family,
                 arch=arch, scenario=scenario, workload=spec.to_dict(),
-                ahead_tick_fraction=(frontend.stats["ahead_ticks"]
-                                     / max(1, frontend.stats["ticks"])))
+                ahead_tick_fraction=(
+                    obs.registry.get("serve_ahead_ticks_total").value
+                    / max(1.0, obs.registry.get("serve_decode_ticks_total").value)))
             rows.append(row)
             report(f"   {family:12s} {scenario:8s} "
                    f"goodput {row['goodput_tok_per_s']:7.1f} tok/s "

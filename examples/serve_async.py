@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.obs import Observer
 from repro.serve import AsyncEngine
 
 
@@ -42,8 +43,9 @@ async def main():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
 
+    obs = Observer()  # counters, scheduler events and spans
     frontend = AsyncEngine(model, params, slots=2, max_len=96,
-                           block_size=8, prefill_chunk=8)
+                           block_size=8, prefill_chunk=8, obs=obs)
     keep = frontend.submit([1, 2, 3, 4, 5], max_tokens=20)
     drop = frontend.submit([7, 8, 9], max_tokens=20)
 
@@ -69,13 +71,14 @@ async def main():
     solo = reference(model, params, [7, 8, 9], 20)
     assert dropped == solo[:len(dropped)] and len(dropped) < len(solo)
     assert drop.cancelled and drop.finish_reason == "user"
-    assert frontend.stats["ahead_ticks"] > 0  # double buffering engaged
+    ahead = obs.registry.get("serve_ahead_ticks_total").value
+    ticks = obs.registry.get("serve_decode_ticks_total").value
+    assert ahead > 0  # double buffering engaged
 
     print(f"streamed {len(kept)} tokens (identical to the solo reference); "
           f"cancelled the second request after {len(dropped)} tokens "
           f"(a strict prefix of its reference)")
-    print(f"dispatch-ahead ticks: {frontend.stats['ahead_ticks']}"
-          f"/{frontend.stats['ticks']}")
+    print(f"dispatch-ahead ticks: {ahead:.0f}/{ticks:.0f}")
     print("OK")
 
 

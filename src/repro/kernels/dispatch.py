@@ -35,13 +35,12 @@ import contextlib
 import contextvars
 import os
 import re
-import time
 
 import jax
 import jax.numpy as jnp
 
 from ..core.ttd import TTSpec
-from ..obs import ENV_KERNEL_TIMING, MetricsRegistry
+from ..obs import MetricsRegistry
 from . import ref
 from .epilogue import apply_epilogue
 from .int4_matmul import int4_matmul_pallas
@@ -104,20 +103,17 @@ def resolve_backend(explicit: str | None = None, *, role: str = "",
 # call) — NOT one executed device launch of a cached jitted program.  That is
 # exactly what the consumers need: ``resolved_backend(role)`` answers "which
 # backend did the program that actually traced in this process bake in?",
-# replacing benchmark self-reports of the *requested* backend.  Counters and
-# (opt-in) wall-time histograms live in a module-local zero-dep registry so
-# recording costs a dict lookup + float add and never touches the device;
-# the ``REPRO_OBS_KERNEL_TIMING=1`` fence only ever fires on *eager* calls —
-# under a jit trace the inputs are Tracers and the fence is skipped, keeping
-# the no-device-syncs overhead contract.
+# replacing benchmark self-reports of the *requested* backend.  Counters live
+# in a module-local zero-dep registry so recording costs a dict lookup + float
+# add and never touches the device.  Kernel time comes from a profiler trace,
+# where every Pallas kernel carries its stable ``name=``.
 # ---------------------------------------------------------------------------
 _METRICS = MetricsRegistry()
 _LAST_RESOLVED: dict[str, str] = {}
 
 
 def kernel_metrics() -> MetricsRegistry:
-    """Registry holding ``kernel_dispatch_total{role,backend}`` counters and
-    (with ``REPRO_OBS_KERNEL_TIMING=1``) ``kernel_wall_seconds`` histograms."""
+    """Registry holding the ``kernel_dispatch_total{role,backend}`` counters."""
     return _METRICS
 
 
@@ -139,24 +135,10 @@ def reset_dispatch_metrics() -> None:
     _LAST_RESOLVED.clear()
 
 
-def _timing_t0(x):
-    """perf_counter start stamp, or None when timing is off / under a trace."""
-    if not os.environ.get(ENV_KERNEL_TIMING, "") or \
-            os.environ.get(ENV_KERNEL_TIMING) in ("0", "false", "no", "off"):
-        return None
-    if isinstance(x, jax.core.Tracer):
-        return None
-    return time.perf_counter()
-
-
-def _record_dispatch(role: str, backend: str, out, t0):
-    """Count the (role, backend) dispatch; fence + time it when requested."""
+def _record_dispatch(role: str, backend: str, out):
+    """Count the (role, backend) dispatch."""
     _LAST_RESOLVED[role] = backend
     _METRICS.counter("kernel_dispatch_total", role=role, backend=backend).inc()
-    if t0 is not None:
-        jax.block_until_ready(out)
-        _METRICS.histogram("kernel_wall_seconds", role=role,
-                           backend=backend).observe(time.perf_counter() - t0)
     return out
 
 
@@ -174,14 +156,13 @@ def dense_linear(x, w, *, scale=None, bias=None, residual=None,
     records the honest ``xla`` label).
     """
     del backend
-    t0 = _timing_t0(x)
     y = jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     y = apply_epilogue(y, scale=scale, bias=bias, residual=residual,
                        activation=activation)
-    return _record_dispatch(role or "dense", "xla", y.astype(x.dtype), t0)
+    return _record_dispatch(role or "dense", "xla", y.astype(x.dtype))
 
 
 def tt_linear(x, cores, spec: TTSpec, *, scale=None, bias=None, residual=None,
@@ -189,7 +170,6 @@ def tt_linear(x, cores, spec: TTSpec, *, scale=None, bias=None, residual=None,
               block_b: int | None = None, role: str = ""):
     """(…, N) -> (…, M) through the staged TT contraction + fused epilogue."""
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(x)
     if backend == "ref":
         # keep leading dims intact: activation sharding (batch→data,
         # seq→model) propagates untouched through the stages (DESIGN.md §4)
@@ -204,7 +184,7 @@ def tt_linear(x, cores, spec: TTSpec, *, scale=None, bias=None, residual=None,
                              block_b=block_b,
                              interpret=(backend == "pallas-interpret"))
         y = y.reshape(*lead, spec.n_out)
-    return _record_dispatch(role or "tt", backend, y, t0)
+    return _record_dispatch(role or "tt", backend, y)
 
 
 def tt_embed(ids, cores, spec: TTSpec, *, backend: str | None = None,
@@ -219,7 +199,6 @@ def tt_embed(ids, cores, spec: TTSpec, *, backend: str | None = None,
     one-hot-gather tile kernel (``kernels/tt_embed.py``).
     """
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(ids)
     if backend == "ref":
         y = ref.tt_embedding(ids, cores, spec)
     else:
@@ -228,7 +207,7 @@ def tt_embed(ids, cores, spec: TTSpec, *, backend: str | None = None,
         y = tt_embed_pallas(flat, cores, spec,
                             interpret=(backend == "pallas-interpret"))
         y = y.reshape(*lead, spec.n_in)
-    return _record_dispatch(role, backend, y, t0)
+    return _record_dispatch(role, backend, y)
 
 
 def paged_attention(q, cache, block_tables, qpos, *, sm_scale=None,
@@ -242,7 +221,6 @@ def paged_attention(q, cache, block_tables, qpos, *, sm_scale=None,
     (Sq > 1) goes through :func:`prefill_attention` instead.
     """
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(q)
     if backend == "ref":
         y = ref.paged_attention(q[:, None], cache, block_tables,
                                 qpos[:, None], sm_scale=sm_scale)[:, 0]
@@ -250,7 +228,7 @@ def paged_attention(q, cache, block_tables, qpos, *, sm_scale=None,
         y = paged_attention_pallas(q, cache, block_tables, qpos,
                                    sm_scale=sm_scale,
                                    interpret=(backend == "pallas-interpret"))
-    return _record_dispatch(role, backend, y, t0)
+    return _record_dispatch(role, backend, y)
 
 
 def prefill_attention(q, qpos, *, cache=None, block_tables=None, k=None,
@@ -285,7 +263,6 @@ def prefill_attention(q, qpos, *, cache=None, block_tables=None, k=None,
         raise ValueError("k_scale and v_scale must be passed together")
     if k_scale is not None and not ring:
         raise ValueError("k_scale/v_scale are ring-layout only")
-    t0 = _timing_t0(q)
     if paged:
         if backend == "ref":
             y = ref.paged_attention(q, cache, block_tables, qpos,
@@ -303,7 +280,7 @@ def prefill_attention(q, qpos, *, cache=None, block_tables=None, k=None,
             q, qpos, k=k, v=v, kpos=kpos, window=window, sm_scale=sm_scale,
             k_scale=k_scale, v_scale=v_scale,
             interpret=(backend == "pallas-interpret"))
-    return _record_dispatch(role, backend, y, t0)
+    return _record_dispatch(role, backend, y)
 
 
 def rglru_scan(log_a, gx, h0, pos=None, *, scan_dtype=None,
@@ -325,13 +302,12 @@ def rglru_scan(log_a, gx, h0, pos=None, *, scan_dtype=None,
         raise ValueError(f"h0 must be (B, W) = {(log_a.shape[0], log_a.shape[2])}; "
                          f"got {h0.shape}")
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(log_a)
     if backend == "ref":
         out = ref.rglru_scan(log_a, gx, h0, pos, scan_dtype=scan_dtype)
     else:
         out = rglru_scan_pallas(log_a, gx, h0, pos, scan_dtype=scan_dtype,
                                 interpret=(backend == "pallas-interpret"))
-    return _record_dispatch(role, backend, out, t0)
+    return _record_dispatch(role, backend, out)
 
 
 def wkv_scan(r, k, v, w, u, state0, pos=None, *, state_scale=None,
@@ -358,7 +334,6 @@ def wkv_scan(r, k, v, w, u, state0, pos=None, *, state_scale=None,
                          f"got state0 {state0.dtype} with state_scale "
                          f"{'set' if state_scale is not None else 'None'}")
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(r)
     if backend == "ref":
         out = ref.wkv_scan(r, k, v, w, u, state0, pos,
                            state_scale=state_scale)
@@ -366,7 +341,7 @@ def wkv_scan(r, k, v, w, u, state0, pos=None, *, state_scale=None,
         out = wkv_scan_pallas(r, k, v, w, u, state0, pos,
                               state_scale=state_scale,
                               interpret=(backend == "pallas-interpret"))
-    return _record_dispatch(role, backend, out, t0)
+    return _record_dispatch(role, backend, out)
 
 
 def int4_matmul(x, qweight, scales, *, group: int = 128, scale=None, bias=None,
@@ -374,7 +349,6 @@ def int4_matmul(x, qweight, scales, *, group: int = 128, scale=None, bias=None,
                 backend: str | None = None, role: str = ""):
     """(…, K) -> (…, M) through the w4a16 kernel + fused epilogue."""
     backend = resolve_backend(backend, role=role)
-    t0 = _timing_t0(x)
     if backend == "ref":
         y = ref.int4_matmul(x, qweight, scales, group=group, scale=scale,
                             bias=bias, residual=residual,
@@ -388,4 +362,4 @@ def int4_matmul(x, qweight, scales, *, group: int = 128, scale=None, bias=None,
                                bias=bias, residual=rf, activation=activation,
                                interpret=(backend == "pallas-interpret"))
         y = y.reshape(*lead, qweight.shape[0])
-    return _record_dispatch(role or "int4", backend, y, t0)
+    return _record_dispatch(role or "int4", backend, y)

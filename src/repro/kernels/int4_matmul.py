@@ -109,6 +109,7 @@ def int4_matmul_pallas(x: jax.Array, qweight: jax.Array, scales: jax.Array, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, bm), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], qweight.shape[0]), x.dtype),
+        name="int4_matmul",
         interpret=interpret,
     )(x, qweight, scales, *extra)
     return out[:b, :m] if (pad_b or pad_m) else out

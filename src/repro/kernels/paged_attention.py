@@ -142,6 +142,7 @@ def paged_attention_pallas(q: jax.Array, cache: dict, block_tables: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(block_tables.astype(jnp.int32).reshape(-1), qpos.astype(jnp.int32),
       *args)

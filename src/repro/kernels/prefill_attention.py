@@ -233,6 +233,7 @@ def prefill_attention_pallas(q: jax.Array, qpos: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="prefill_attention",
         interpret=interpret,
     )(*scalars, q, qpos[:, :, None], *kv_args)
     return out[:, :sq] if pad_q else out

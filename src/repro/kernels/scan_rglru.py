@@ -124,6 +124,7 @@ def rglru_scan_pallas(log_a, gx, h0, pos=None, *, scan_dtype=None,
                 jax.ShapeDtypeStruct(log_a.shape, out_dtype),
                 jax.ShapeDtypeStruct(h0.shape, f32),
             ],
+            name="rglru_scan",
             interpret=interpret,
         )(log_a, gx, h0, pos)
         return h[:, :, :w] if pad_w else h, h_last[:, :w] if pad_w else h_last
@@ -154,6 +155,7 @@ def rglru_scan_pallas(log_a, gx, h0, pos=None, *, scan_dtype=None,
             jax.ShapeDtypeStruct((b, sp, w + pad_w), out_dtype),
             jax.ShapeDtypeStruct((b, w + pad_w), f32),
         ],
+        name="rglru_scan",
         interpret=interpret,
     )(log_a, gx, h0, pos)
     return h[:, :s, :w], h_last[:, :w]

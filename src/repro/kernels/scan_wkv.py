@@ -153,6 +153,7 @@ def wkv_scan_pallas(r, k, v, w, u, state0, pos=None, *, state_scale=None,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="wkv_scan",
         interpret=interpret,
     )(*args)
     if quantized:

@@ -95,6 +95,7 @@ def tt_embed_pallas(ids: jax.Array, cores: list[jax.Array], spec: TTSpec, *,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bt, spec.n_in), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ids32.shape[0], spec.n_in), jnp.float32),
+        name="tt_embed",
         interpret=interpret,
     )(ids32, *cores)
     return out[:t] if pad else out

@@ -182,6 +182,7 @@ def tt_linear_pallas(x: jax.Array, cores: list[jax.Array], spec: TTSpec, *,
         out_shape=jax.ShapeDtypeStruct((x.shape[0], spec.n_out), x.dtype),
         scratch_shapes=[pltpu.VMEM((spec.n_out, bb), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="tt_linear",
         interpret=interpret,
     )(x, *kcores, *extra)
     return out[:b] if pad else out

@@ -6,7 +6,10 @@ One :class:`Observer` bundles the three layers:
   mergeable fixed-bucket histograms with exact-to-one-bucket percentiles),
 * a :class:`~repro.obs.trace.Trace` of structured scheduler events
   (monotonic timestamps, optionally streamed to JSONL),
-* optional ``jax.profiler`` trace annotations around dispatch regions.
+* spans (:meth:`Observer.span`): timed regions of the serving loop, each a
+  ``span`` event in that trace and a ``jax.profiler.TraceAnnotation``
+  ``<name>#<sid>`` in a profiler trace, and ``compile`` events with the
+  ``serve_compiles_total{fun}`` counter for every program JAX builds.
 
 **Overhead contract:** everything is off by default.  Components take an
 ``obs=None`` argument: ``None`` resolves to the process-default observer
@@ -22,18 +25,14 @@ Env knobs (read once, at first ``default_observer()`` call):
 ====================================  =======================================
 ``REPRO_OBS=1``                       enable the process-default observer
 ``REPRO_OBS_JSONL=<path>``            stream trace events to ``<path>``
-``REPRO_OBS_PROFILER=1``              ``jax.profiler`` annotations on
-                                      prefill/decode dispatch
-``REPRO_OBS_KERNEL_TIMING=1``         per-(role, backend) kernel wall-time
-                                      histograms in ``kernels.dispatch``
-                                      (fences with ``block_until_ready``;
-                                      eager calls only — never inside jit)
 ``REPRO_OBS_POOL_EVERY=<n>``          sample pool gauges every n ticks (1)
 ====================================  =======================================
 """
 from __future__ import annotations
 
 import os
+import time
+import weakref
 from dataclasses import dataclass
 
 from .export import (  # noqa: F401  (public re-exports)
@@ -52,12 +51,10 @@ from .registry import (  # noqa: F401
     MetricsRegistry,
     exp_buckets,
 )
-from .trace import Trace, annotate, maybe_annotate  # noqa: F401
+from .trace import Span, Trace  # noqa: F401
 
 ENV_ENABLE = "REPRO_OBS"
 ENV_JSONL = "REPRO_OBS_JSONL"
-ENV_PROFILER = "REPRO_OBS_PROFILER"
-ENV_KERNEL_TIMING = "REPRO_OBS_KERNEL_TIMING"
 ENV_POOL_EVERY = "REPRO_OBS_POOL_EVERY"
 
 
@@ -71,8 +68,6 @@ class ObsConfig:
 
     enabled: bool = True
     jsonl_path: str | None = None      # stream trace events here
-    profiler_annotations: bool = False  # jax.profiler spans on dispatch
-    kernel_timing: bool = False         # fenced per-kernel wall histograms
     pool_sample_every: int = 1          # ticks between pool gauge samples
 
     @classmethod
@@ -80,14 +75,37 @@ class ObsConfig:
         return cls(
             enabled=_truthy(os.environ.get(ENV_ENABLE)),
             jsonl_path=os.environ.get(ENV_JSONL) or None,
-            profiler_annotations=_truthy(os.environ.get(ENV_PROFILER)),
-            kernel_timing=_truthy(os.environ.get(ENV_KERNEL_TIMING)),
             pool_sample_every=max(1, int(os.environ.get(ENV_POOL_EVERY, "1"))),
         )
 
 
+# jax.monitoring events of building a program, by the stage they time
+_COMPILE_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                   "/jax/core/compile/backend_compile_duration": "compile"}
+_LIVE: weakref.WeakSet = weakref.WeakSet()  # observers that record compiles
+_LISTENING: list = []  # the listener, once registered (one per process)
+
+
+def _on_compile(event: str, duration: float, **kwargs) -> None:
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    fun = str(kwargs.get("fun_name", "?"))
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]  # the backend stage names ``jit(<fun>)``
+    for obs in list(_LIVE):
+        obs._compiled(fun, stage, duration)
+
+
 class Observer:
-    """Live instrumentation handle: registry + trace (+ profiler spans)."""
+    """Live instrumentation handle: registry + trace + spans.
+
+    Every live observer records each program JAX traces or compiles in the
+    process: a ``compile`` event naming the function, its stage and the
+    span open at the time, and, per executable built or loaded,
+    ``serve_compiles_total{fun}``.  After warm-up that counter should not
+    move; a rise names the function that recompiled.
+    """
 
     def __init__(self, config: ObsConfig | None = None, *,
                  registry: MetricsRegistry | None = None):
@@ -96,13 +114,31 @@ class Observer:
         writer = (JsonlWriter(self.config.jsonl_path)
                   if self.config.jsonl_path else None)
         self.trace = Trace(writer=writer)
+        self._stack: list[Span] = []  # open nested spans (one loop thread)
+        if not _LISTENING:
+            import jax.monitoring
+            jax.monitoring.register_event_duration_secs_listener(_on_compile)
+            _LISTENING.append(_on_compile)
+        _LIVE.add(self)
 
     def event(self, ev: str, t: float | None = None, **fields) -> dict:
         return self.trace.emit(ev, t=t, **fields)
 
-    def annotate(self, name: str):
-        """Profiler span when ``profiler_annotations`` is on, else no-op."""
-        return maybe_annotate(name, self.config.profiler_annotations)
+    def span(self, name: str, **fields) -> Span:
+        """A nested span for a ``with`` block: ``with obs.span("serve/x"):``."""
+        return Span(self.trace, self._stack, name, fields)
+
+    def overlay(self, name: str, **fields) -> Span:
+        """An overlay span, open from now until its ``close()``; it need not
+        nest with the spans opened meanwhile."""
+        return Span(self.trace, None, name, fields).open()
+
+    def _compiled(self, fun: str, stage: str, dur: float) -> None:
+        if stage == "compile":
+            self.registry.counter("serve_compiles_total", fun=fun).inc()
+        self.trace.emit("compile", t=time.perf_counter() - dur, fun=fun,
+                        stage=stage, dur=dur,
+                        sid=self._stack[-1].sid if self._stack else -1)
 
     def close(self) -> None:
         self.trace.close()

@@ -22,7 +22,7 @@ import math
 from pathlib import Path
 
 from .registry import Counter, Gauge, Histogram, MetricsRegistry
-from .trace import EVENT_FIELDS
+from .trace import EVENT_FIELDS, SPAN_FIELDS
 
 # ---------------------------------------------------------------------------
 # JSONL event log
@@ -68,19 +68,29 @@ _FIELD_TYPES = {
     "rids": list, "ttft_s": (int, float), "active": int, "reason": str,
     "n_out": int, "utilization": (int, float), "free_blocks": int,
     "live_tokens": int, "active_slots": int, "deadline_s": (int, float),
+    "name": str, "dur": (int, float), "sid": int, "parent": int,
+    "fun": str, "stage": str, "ahead": bool, "starved": bool,
 }
 EVENT_SCHEMA = {
     ev: {**_COMMON, **{f: _FIELD_TYPES[f] for f in fields}}
     for ev, fields in EVENT_FIELDS.items()
 }
+_OPTIONAL = {"span": {f: _FIELD_TYPES[f] for f in SPAN_FIELDS}}
+
+
+def _type_error(value, typ) -> bool:
+    """A bool passes only a bool field (it is an int to ``isinstance``)."""
+    if isinstance(value, bool):
+        return typ is not bool
+    return not isinstance(value, typ)
 
 
 def validate_events(events) -> list[str]:
     """Schema errors for an iterable of event dicts ([] = valid).
 
-    Checks: known event type, required fields present with the right types,
-    finite timestamps, and non-decreasing ``seq`` (emission order survived
-    serialization).
+    Checks: known event type, required fields present with the right types
+    (a span's optional fields too, where present), finite timestamps, and
+    non-decreasing ``seq`` (emission order survived serialization).
     """
     errors = []
     last_seq = -1
@@ -97,7 +107,11 @@ def validate_events(events) -> list[str]:
         for f, typ in schema.items():
             if f not in e:
                 errors.append(f"{where} ({ev}): missing field {f!r}")
-            elif not isinstance(e[f], typ) or isinstance(e[f], bool):
+            elif _type_error(e[f], typ):
+                errors.append(f"{where} ({ev}): field {f!r} has "
+                              f"{type(e[f]).__name__}, want {typ}")
+        for f, typ in _OPTIONAL.get(ev, {}).items():
+            if f in e and _type_error(e[f], typ):
                 errors.append(f"{where} ({ev}): field {f!r} has "
                               f"{type(e[f]).__name__}, want {typ}")
         t = e.get("t")

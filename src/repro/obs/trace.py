@@ -1,4 +1,4 @@
-"""Structured scheduler event trace + optional ``jax.profiler`` annotations.
+"""Structured scheduler event trace and the spans of the serving loop.
 
 The serving engine narrates its scheduling decisions as a flat stream of
 dict events — one per admit / prefill chunk / decode tick / preemption /
@@ -10,14 +10,15 @@ ordering-invariant tests replay (submit ≤ admit ≤ first token ≤ finish;
 every preempt is followed by a re-admission), and ``repro.obs.export``
 validates and persists it as JSONL.
 
-``annotate`` wraps a region in a ``jax.profiler.TraceAnnotation`` so the
-engine's prefill/decode dispatches show up as named spans in a TensorBoard
-/ Perfetto profile; it is import-light and a no-op-cost ``nullcontext``
-when disabled.
+A :class:`Span` times one region of the serving loop and records it as a
+``span`` event when it closes.  It also wraps the region in a
+``jax.profiler.TraceAnnotation`` named ``<name>#<sid>``, so a profiler
+trace and the event log pair span for span by ``sid``: the annotation's
+start minus the event's ``t`` is the offset between the trace's clock and
+``perf_counter``, the same for every span.
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import time
 
@@ -36,9 +37,18 @@ EVENT_FIELDS: dict[str, tuple[str, ...]] = {
     "finish": ("rid", "tick", "reason", "n_out"),
     "pool_sample": ("tick", "utilization", "free_blocks", "live_tokens",
                     "active_slots"),
+    # one timed region (``t`` is its start); spans may also carry the
+    # optional fields of ``SPAN_FIELDS``
+    "span": ("name", "dur", "sid", "parent"),
+    # a jaxpr trace or backend compile, inside span ``sid`` (-1: none)
+    "compile": ("fun", "stage", "dur", "sid"),
 }
+# fields a span carries where they apply, checked by type when present
+SPAN_FIELDS = ("rids", "n_tokens", "chunk", "n_chunks", "tick", "active",
+               "ahead", "starved")
 
 _seq = itertools.count()
+_sid = itertools.count()
 
 
 class Trace:
@@ -75,14 +85,51 @@ class Trace:
             self._writer.close()
 
 
-def annotate(name: str):
-    """``jax.profiler.TraceAnnotation`` region named ``name``.
+class Span:
+    """One timed region, recorded as a ``span`` event when it closes.
 
-    Import is local so the pure-Python metrics path never pulls in jax.
+    Opening draws ``sid`` from a counter of its own (``seq`` keeps emission
+    order), enters ``jax.profiler.TraceAnnotation(f"{name}#{sid}")`` and
+    stamps ``t``.  Given a ``stack`` (a ``with`` block), the span nests:
+    it is pushed while open, and spans opened inside it name it
+    ``parent``.  Without one it is an overlay, opened and closed at two
+    points of the loop that need not nest (``Observer.overlay``), with
+    parent -1.  ``fields`` may be filled in while the span is open.
     """
-    import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
 
+    __slots__ = ("_trace", "_stack", "name", "fields", "sid", "parent", "t",
+                 "_ann")
 
-def maybe_annotate(name: str, enabled: bool):
-    return annotate(name) if enabled else contextlib.nullcontext()
+    def __init__(self, trace: Trace, stack: list | None, name: str,
+                 fields: dict):
+        self._trace = trace
+        self._stack = stack
+        self.name = name
+        self.fields = fields
+
+    def open(self) -> "Span":
+        import jax.profiler  # local: the metrics path never pulls in jax
+
+        self.sid = next(_sid)
+        stack = self._stack
+        self.parent = stack[-1].sid if stack else -1
+        if stack is not None:
+            stack.append(self)
+        self._ann = jax.profiler.TraceAnnotation(f"{self.name}#{self.sid}")
+        self._ann.__enter__()
+        self.t = time.perf_counter()
+        return self
+
+    def close(self) -> dict:
+        dur = time.perf_counter() - self.t
+        self._ann.__exit__(None, None, None)
+        if self._stack is not None:
+            self._stack.pop()
+        return self._trace.emit("span", t=self.t, name=self.name, dur=dur,
+                                sid=self.sid, parent=self.parent,
+                                **self.fields)
+
+    __enter__ = open
+
+    def __exit__(self, *exc) -> None:
+        self.close()

@@ -45,8 +45,15 @@ Observability (DESIGN.md §9): pass ``obs=`` an
 ``REPRO_OBS=1``) and the engine emits structured scheduler events
 (admit / prefill_chunk / decode_tick / preempt / finish / pool_sample),
 queue-time / TTFT / inter-token latency histograms, and block-pool
-utilization gauges.  Disabled (the default), the hot path pays one
-``is None`` check per site — no events, no allocation, no device syncs.
+utilization gauges, and times each stage in a span: ``serve/admit`` (with
+``serve/prefill_chunk``, ``serve/prefill_wait`` and ``serve/first_sample``
+inside), ``serve/decode_schedule``, ``serve/decode_dispatch`` (``ahead``,
+``starved``), ``serve/decode_collect``, and the overlay
+``serve/host_bound``: from a sync that left no device work in flight while
+requests are live until the next dispatch has enqueued its device work.
+Disabled (the default), the hot
+path pays one ``is None`` check per site — no events, no allocation, no
+device syncs.
 """
 from __future__ import annotations
 
@@ -224,6 +231,8 @@ class Engine:
         self.obs = resolve_observer(obs)
         self._tick_no = 0
         self._t_last_tok: dict[int, float] = {}  # slot -> last token stamp
+        self._host_bound = None   # open serve/host_bound overlay span
+        self._last_logits = None  # the latest decode dispatch's result
         if self.obs is not None:
             reg = self.obs.registry
             self._h_queue = reg.histogram("serve_queue_seconds")
@@ -234,6 +243,7 @@ class Engine:
             self._c_preempt = reg.counter("serve_preemptions_total")
             self._c_cancel = reg.counter("serve_cancellations_total")
             self._c_deadline = reg.counter("serve_deadline_miss_total")
+            self._c_ahead = reg.counter("serve_ahead_ticks_total")
             self._g_active = reg.gauge("serve_active_slots")
             if self.manager is not None:
                 self._g_util = reg.gauge("serve_pool_utilization")
@@ -397,6 +407,8 @@ class Engine:
         while self.pending() and ticks < max_ticks:
             self.tick()
             ticks += 1
+        if self.obs is not None:
+            self._host_bound_close()
         return self.finished[start:]
 
     @property
@@ -498,29 +510,45 @@ class Engine:
         prompt plus one lookahead token, then prefill them together in
         fixed-width chunks."""
         free_slots = [s for s in range(self.slots) if self.slot_req[s] is None]
+        if not (self.queue and free_slots):
+            return
+        span = self.obs.span("serve/admit") if self.obs is not None else _NULL_CTX
+        with span:
+            batch = self._take_batch(free_slots)
+            if self.obs is not None:
+                span.fields.update(
+                    rids=[req.rid for _, req in batch],
+                    n_tokens=sum(len(self._seq_tokens(r)) for _, r in batch))
+            if batch:
+                self._prefill_batch(batch)
+
+    def _take_batch(self, free_slots: list[int]) -> list[tuple[int, Request]]:
+        """(slot, request) pairs admitted now, in the policy's order."""
         batch: list[tuple[int, Request]] = []
         reserve = 0  # lookahead blocks promised to earlier batch members
-        if self.queue and free_slots:
-            order = self.admission.order(list(self.queue), time.perf_counter())
-            for req in order:
-                if not free_slots or len(batch) >= self.prefill_batch:
-                    break
-                n_tok = len(self._seq_tokens(req))
-                if self.manager is not None:
-                    # admission wants the prompt *plus one lookahead token*
-                    # free — counting lookahead already reserved by this
-                    # batch's earlier members — so a fresh admission doesn't
-                    # immediately preempt on its first decode tick
-                    bs = self.manager.block_size
-                    need = blocks_for(n_tok + 1, bs)
-                    if need + reserve > self.manager.num_free or \
-                            not self.manager.allocate(req.rid, n_tok):
-                        break  # head-of-line blocks: keep the policy order
-                    reserve += need - blocks_for(n_tok, bs)
-                self._remove_from_queue(req)
-                batch.append((free_slots.pop(0), req))
-        if not batch:
-            return
+        order = self.admission.order(list(self.queue), time.perf_counter())
+        for req in order:
+            if not free_slots or len(batch) >= self.prefill_batch:
+                break
+            n_tok = len(self._seq_tokens(req))
+            if self.manager is not None:
+                # admission wants the prompt *plus one lookahead token*
+                # free — counting lookahead already reserved by this
+                # batch's earlier members — so a fresh admission doesn't
+                # immediately preempt on its first decode tick
+                bs = self.manager.block_size
+                need = blocks_for(n_tok + 1, bs)
+                if need + reserve > self.manager.num_free or \
+                        not self.manager.allocate(req.rid, n_tok):
+                    break  # head-of-line blocks: keep the policy order
+                reserve += need - blocks_for(n_tok, bs)
+            self._remove_from_queue(req)
+            batch.append((free_slots.pop(0), req))
+        return batch
+
+    def _prefill_batch(self, batch: list[tuple[int, Request]]) -> None:
+        """Prefill ``batch`` in chunks, stamp first tokens and sample them."""
+        chunk_span = None
         if self.obs is not None:
             t_admit = time.perf_counter()
             for s, req in batch:
@@ -529,6 +557,17 @@ class Engine:
                                n_tokens=len(self._seq_tokens(req)))
                 if not req.t_first:  # first admission, not a preempt replay
                     self._h_queue.observe(t_admit - req.t_submit)
+            rids = [req.rid for _, req in batch]
+
+            @contextlib.contextmanager
+            def chunk_span(c, n_chunks):
+                with self.obs.span("serve/prefill_chunk", chunk=c,
+                                   n_chunks=n_chunks):
+                    yield
+                    if c == 0:  # the device has work: host-bound ends
+                        self._host_bound_close()
+                self.obs.event("prefill_chunk", tick=self._tick_no, chunk=c,
+                               n_chunks=n_chunks, rids=rids)
         self._reset_slots([s for s, _ in batch])
         if self.session.needs_encoder_ctx:
             for s, req in batch:
@@ -542,40 +581,53 @@ class Engine:
         prompts: list[list[int] | None] = [None] * self.slots
         for s, req in batch:
             prompts[s] = self._seq_tokens(req)
-        on_chunk = None
-        if self.obs is not None:
-            rids = [req.rid for _, req in batch]
-
-            def on_chunk(c, n_chunks):
-                self.obs.event("prefill_chunk", tick=self._tick_no, chunk=c,
-                               n_chunks=n_chunks, rids=rids)
-        with (self.obs.annotate("repro/serve/prefill")
-              if self.obs is not None else _NULL_CTX):
-            logits, self.state = steps.chunked_prefill(
-                self._prefill, self.params, self.state, prompts,
-                chunk=self.prefill_chunk, on_chunk=on_chunk)
+        logits, self.state = steps.chunked_prefill(
+            self._prefill, self.params, self.state, prompts,
+            chunk=self.prefill_chunk, span=chunk_span)
+        with (self.obs.span("serve/prefill_wait") if self.obs is not None
+              else _NULL_CTX):
             # first-token latency: stamp only after the device finishes
             jax.block_until_ready(logits)
         t_ready = time.perf_counter()
-        for s, req in batch:
-            fresh = not req.t_first
-            if fresh:
-                req.t_first = t_ready
-                if self.obs is not None:
-                    self._h_ttft.observe(t_ready - req.t_submit)
-                    self.obs.event("first_token", t=t_ready, rid=req.rid,
-                                   tick=self._tick_no,
-                                   ttft_s=t_ready - req.t_submit)
-            self._t_last_tok[s] = t_ready
-            tok = self._sample(logits[s])
-            if self._emit(req, tok):  # eos on first token / max_tokens=1
-                self._t_last_tok.pop(s, None)
-                if self.manager is not None:
-                    self.manager.free(req.rid)
-                continue
-            self.slot_req[s] = req
-            self.slot_pos[s] = len(prompts[s])
-            self._admit_order.append(s)
+        if self.obs is not None:
+            self._host_bound_open()
+        with (self.obs.span("serve/first_sample") if self.obs is not None
+              else _NULL_CTX):
+            for s, req in batch:
+                fresh = not req.t_first
+                if fresh:
+                    req.t_first = t_ready
+                    if self.obs is not None:
+                        self._h_ttft.observe(t_ready - req.t_submit)
+                        self.obs.event("first_token", t=t_ready, rid=req.rid,
+                                       tick=self._tick_no,
+                                       ttft_s=t_ready - req.t_submit)
+                self._t_last_tok[s] = t_ready
+                tok = self._sample(logits[s])
+                if self._emit(req, tok):  # eos on first token / max_tokens=1
+                    self._t_last_tok.pop(s, None)
+                    if self.manager is not None:
+                        self.manager.free(req.rid)
+                    continue
+                self.slot_req[s] = req
+                self.slot_pos[s] = len(prompts[s])
+                self._admit_order.append(s)
+        if self.obs is not None and not self.pending():
+            self._host_bound_close()
+
+    # -- host-bound device idle (obs on) --------------------------------------
+    def _host_bound_open(self) -> None:
+        """A sync just returned with no device work in flight while requests
+        are live: the device waits on the host until the next dispatch."""
+        if self._host_bound is None:
+            self._host_bound = self.obs.overlay("serve/host_bound")
+
+    def _host_bound_close(self) -> None:
+        """The next dispatch, once its device work is enqueued, (or the
+        drain) ends the host-bound stretch."""
+        if self._host_bound is not None:
+            self._host_bound.close()
+            self._host_bound = None
 
     # -- decode / preemption --------------------------------------------------
     def _preempt_newest(self) -> int | None:
@@ -602,38 +654,40 @@ class Engine:
         """Host-side tick planning: grow block tables (preempting on
         exhaustion), pick the active slots, and build the token/position
         batch.  Returns ``None`` when nothing is active."""
-        # block backends: grow each active sequence's table to cover the
-        # incoming token, preempting the newest-admitted sequence on block
-        # exhaustion (the grower itself, if it is the newest — FCFS favors
-        # older requests)
-        if self.manager is not None:
-            for s in list(self._admit_order):
-                req = self.slot_req[s]
-                if req is None:
-                    continue
-                while not self.manager.ensure(req.rid, int(self.slot_pos[s]) + 1):
-                    victim = self._preempt_newest()
-                    if victim == s:
-                        break  # the grower was evicted; retries on re-admission
-                    if victim is None:  # unreachable: submit-time capacity check
-                        raise RuntimeError(
-                            f"block pool too small: sequence {req.rid} alone "
-                            f"cannot grow to {int(self.slot_pos[s]) + 1} tokens")
-        active = [s for s in range(self.slots) if self.slot_req[s] is not None]
-        if not active:
-            return None
-        if self.obs is not None:
-            self._c_ticks.inc()
-            self.obs.event("decode_tick", tick=self._tick_no,
-                           active=len(active))
-        toks = np.zeros((self.slots, 1), np.int32)
-        positions = np.full((self.slots,), -1, np.int32)
-        for s in active:
-            toks[s, 0] = self.slot_req[s].out_tokens[-1]
-            positions[s] = self.slot_pos[s]
-        return TickPlan(active=active,
-                        rids=[self.slot_req[s].rid for s in active],
-                        positions=positions, toks=toks)
+        with (self.obs.span("serve/decode_schedule", ahead=False)
+              if self.obs is not None else _NULL_CTX):
+            # block backends: grow each active sequence's table to cover the
+            # incoming token, preempting the newest-admitted sequence on block
+            # exhaustion (the grower itself, if it is the newest — FCFS favors
+            # older requests)
+            if self.manager is not None:
+                for s in list(self._admit_order):
+                    req = self.slot_req[s]
+                    if req is None:
+                        continue
+                    while not self.manager.ensure(req.rid, int(self.slot_pos[s]) + 1):
+                        victim = self._preempt_newest()
+                        if victim == s:
+                            break  # the grower was evicted; retries on re-admission
+                        if victim is None:  # unreachable: submit-time capacity check
+                            raise RuntimeError(
+                                f"block pool too small: sequence {req.rid} alone "
+                                f"cannot grow to {int(self.slot_pos[s]) + 1} tokens")
+            active = [s for s in range(self.slots) if self.slot_req[s] is not None]
+            if not active:
+                return None
+            if self.obs is not None:
+                self._c_ticks.inc()
+                self.obs.event("decode_tick", tick=self._tick_no,
+                               active=len(active))
+            toks = np.zeros((self.slots, 1), np.int32)
+            positions = np.full((self.slots,), -1, np.int32)
+            for s in active:
+                toks[s, 0] = self.slot_req[s].out_tokens[-1]
+                positions[s] = self.slot_pos[s]
+            return TickPlan(active=active,
+                            rids=[self.slot_req[s].rid for s in active],
+                            positions=positions, toks=toks)
 
     def _plan_ahead(self, plan: TickPlan) -> TickPlan | None:
         """Plan the tick *after* an in-flight ``plan`` without its token
@@ -647,46 +701,68 @@ class Engine:
         in-flight tick first."""
         if not self.greedy:
             return None  # host-side RNG sampling needs the logits on host
-        for i, s in enumerate(plan.active):
-            req = self.slot_req[s]
-            if req is None or req.rid != plan.rids[i] or req.eos is not None:
-                return None
-            # after the in-flight emission the request must still be live:
-            # not its last max_tokens emission, not at the max_len frontier
-            if len(req.out_tokens) + 1 >= req.max_tokens:
-                return None
-            if int(plan.positions[s]) + 1 >= self.max_len - 1:
-                return None
-        if self.manager is not None:
-            for s in plan.active:
-                # position p+1 writes token p+1 -> needs p+2 covered; bail to
-                # the synchronous path rather than preempt around an
-                # uncollected tick
-                if not self.manager.ensure(self.slot_req[s].rid,
-                                           int(plan.positions[s]) + 2):
+        with (self.obs.span("serve/decode_schedule", ahead=True)
+              if self.obs is not None else _NULL_CTX):
+            for i, s in enumerate(plan.active):
+                req = self.slot_req[s]
+                if req is None or req.rid != plan.rids[i] or req.eos is not None:
                     return None
-        positions = np.full((self.slots,), -1, np.int32)
-        for s in plan.active:
-            positions[s] = plan.positions[s] + 1
-        if self.obs is not None:
-            self._c_ticks.inc()
-            # the in-flight tick has not collected yet, so _tick_no still
-            # names it; the ahead tick is the next one
-            self.obs.event("decode_tick", tick=self._tick_no + 1,
-                           active=len(plan.active))
-        return TickPlan(active=list(plan.active), rids=list(plan.rids),
-                        positions=positions, toks=None)
+                # after the in-flight emission the request must still be
+                # live: not its last max_tokens emission, not at the max_len
+                # frontier
+                if len(req.out_tokens) + 1 >= req.max_tokens:
+                    return None
+                if int(plan.positions[s]) + 1 >= self.max_len - 1:
+                    return None
+            if self.manager is not None:
+                for s in plan.active:
+                    # position p+1 writes token p+1 -> needs p+2 covered;
+                    # bail to the synchronous path rather than preempt
+                    # around an uncollected tick
+                    if not self.manager.ensure(self.slot_req[s].rid,
+                                               int(plan.positions[s]) + 2):
+                        return None
+            positions = np.full((self.slots,), -1, np.int32)
+            for s in plan.active:
+                positions[s] = plan.positions[s] + 1
+            if self.obs is not None:
+                self._c_ticks.inc()
+                # the in-flight tick has not collected yet, so _tick_no
+                # still names it; the ahead tick is the next one
+                self.obs.event("decode_tick", tick=self._tick_no + 1,
+                               active=len(plan.active))
+            return TickPlan(active=list(plan.active), rids=list(plan.rids),
+                            positions=positions, toks=None)
 
     def _decode_dispatch(self, plan: TickPlan, device_toks=None):
         """Launch the jitted decode step for ``plan`` (async under jax);
         ``device_toks`` (a (slots, 1) int32 device array) substitutes for the
-        host token batch on the dispatch-ahead path."""
-        self._sync_tables()
-        toks = device_toks if device_toks is not None else jnp.asarray(plan.toks)
-        with (self.obs.annotate("repro/serve/decode")
-              if self.obs is not None else _NULL_CTX):
+        host token batch on the dispatch-ahead path.
+
+        With obs on, an ahead dispatch whose predecessor (the tick still in
+        flight) has already finished is *starved*: the device waited on the
+        host.  ``is_ready`` does not block."""
+        span = _NULL_CTX
+        if self.obs is not None:
+            ahead = device_toks is not None
+            starved = ahead and self._last_logits is not None and \
+                self._last_logits.is_ready()
+            if ahead:
+                self._c_ahead.inc()
+            span = self.obs.span(
+                "serve/decode_dispatch",
+                tick=self._tick_no + 1 if ahead else self._tick_no,
+                active=len(plan.active), ahead=ahead, starved=starved)
+        with span:
+            self._sync_tables()
+            toks = (device_toks if device_toks is not None
+                    else jnp.asarray(plan.toks))
             logits, self.state = self._decode(self.params, self.state, toks,
                                               jnp.asarray(plan.positions))
+            if self.obs is not None:
+                self._host_bound_close()  # the tick is enqueued
+        if self.obs is not None:
+            self._last_logits = logits
         return logits
 
     def _decode_collect(self, plan: TickPlan, logits, toks_host=None):
@@ -694,31 +770,43 @@ class Engine:
         bookkeeping.  ``toks_host`` (a (slots,) int sequence) skips sampling
         — the dispatch-ahead path already pulled the device argmax.  Slots
         whose occupant changed since dispatch (cancelled mid-flight) are
-        skipped; their computed token is discarded."""
-        for i, s in enumerate(plan.active):
-            req = self.slot_req[s]
-            if req is None or req.rid != plan.rids[i]:
-                continue  # cancelled while the tick was in flight
-            tok = (int(toks_host[s]) if toks_host is not None
-                   else self._sample(logits[s]))
-            self.slot_pos[s] += 1
-            if self.obs is not None:
-                # tick-granular inter-token latency: the argmax/device_get in
-                # _sample already materialized this tick's logits, so the
-                # stamp costs no extra device sync
-                now = time.perf_counter()
-                last = self._t_last_tok.get(s)
-                if last is not None:
-                    self._h_intertok.observe(now - last)
-                self._t_last_tok[s] = now
-            if self._emit(req, tok) or self.slot_pos[s] >= self.max_len - 1:
-                if not req.done:  # max_len frontier hit: force-finish
-                    self._finish(req, "max_len")
-                if self.manager is not None:
-                    self.manager.free(req.rid)
-                self.slot_req[s] = None
-                self._admit_order.remove(s)
-                self._t_last_tok.pop(s, None)
+        skipped; their computed token is discarded.
+
+        On the synchronous path (no ``toks_host``) the first sample waits
+        for the tick, after which no device work is in flight: with obs on,
+        ``serve/host_bound`` opens there."""
+        with (self.obs.span("serve/decode_collect", tick=self._tick_no)
+              if self.obs is not None else _NULL_CTX):
+            for i, s in enumerate(plan.active):
+                req = self.slot_req[s]
+                if req is None or req.rid != plan.rids[i]:
+                    continue  # cancelled while the tick was in flight
+                if toks_host is not None:
+                    tok = int(toks_host[s])
+                else:
+                    tok = self._sample(logits[s])
+                    if self.obs is not None:
+                        self._host_bound_open()
+                self.slot_pos[s] += 1
+                if self.obs is not None:
+                    # tick-granular inter-token latency: the argmax/device_get
+                    # in _sample already materialized this tick's logits, so
+                    # the stamp costs no extra device sync
+                    now = time.perf_counter()
+                    last = self._t_last_tok.get(s)
+                    if last is not None:
+                        self._h_intertok.observe(now - last)
+                    self._t_last_tok[s] = now
+                if self._emit(req, tok) or self.slot_pos[s] >= self.max_len - 1:
+                    if not req.done:  # max_len frontier hit: force-finish
+                        self._finish(req, "max_len")
+                    if self.manager is not None:
+                        self.manager.free(req.rid)
+                    self.slot_req[s] = None
+                    self._admit_order.remove(s)
+                    self._t_last_tok.pop(s, None)
+            if self.obs is not None and not self.pending():
+                self._host_bound_close()
 
 
 class PagedEngine(Engine):

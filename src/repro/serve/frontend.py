@@ -36,10 +36,18 @@ Invariants the pump maintains (the dispatch-ahead contract):
 
 The front-end is drained-reusable: the pump exits when the engine drains
 and a later ``submit`` starts a fresh one.
+
+With obs on, the pump's own stretches are spans beside the engine's
+(DESIGN.md §9): ``serve/deliver``, ``serve/token_pull`` (the acknowledged
+sync of the dispatch-ahead path), ``serve/yield`` (each ``await`` that
+hands the loop to consumers and the load generator), and the overlay
+``serve/pump_idle``, from the pump's exit on drain to the ``submit`` that
+restarts it.
 """
 from __future__ import annotations
 
 import asyncio
+import contextlib
 from typing import AsyncIterator
 
 import numpy as np
@@ -48,6 +56,7 @@ from . import steps
 from .engine import Engine, Request
 
 _DONE = object()  # stream sentinel
+_NULL_CTX = contextlib.nullcontext()  # reusable no-op span (obs disabled)
 
 
 class RequestHandle:
@@ -135,7 +144,7 @@ class AsyncEngine:
         else:
             self.engine = Engine(model, params, **engine_kwargs)
         self.dispatch_ahead = dispatch_ahead
-        self.stats = {"ticks": 0, "ahead_ticks": 0}
+        self._idle = None  # open serve/pump_idle overlay span (obs on)
         self._handles: dict[int, RequestHandle] = {}
         self._cancel_q: list[RequestHandle] = []
         self._pump_task: asyncio.Task | None = None
@@ -157,6 +166,9 @@ class AsyncEngine:
         if self._pump_task is None or self._pump_task.done():
             # drained-engine reuse: a finished pump is replaced, never left
             # silently stale
+            if self._idle is not None:
+                self._idle.close()
+                self._idle = None
             self._pump_task = loop.create_task(self._pump())
         return handle
 
@@ -176,6 +188,7 @@ class AsyncEngine:
         eng = self.engine
         in_flight: tuple | None = None  # (plan, logits) — at most one tick
         idle = 0
+        drained = False
         try:
             while True:
                 if in_flight is None:
@@ -183,10 +196,11 @@ class AsyncEngine:
                     eng._expire_deadlines()
                     self._deliver()
                     if not eng.pending():
+                        drained = True
                         break
                     eng._admit()  # batched chunked prefill (device-blocking)
                     self._deliver()  # prefill emitted first tokens
-                    await asyncio.sleep(0)
+                    await self._yield()
                     plan = eng._decode_schedule()
                     if plan is None:
                         eng._finish_tick()
@@ -198,7 +212,7 @@ class AsyncEngine:
                     idle = 0
                     in_flight = (plan, eng._decode_dispatch(plan))
                     # consumers run while the device computes this tick
-                    await asyncio.sleep(0)
+                    await self._yield()
                     continue
                 plan, logits = in_flight
                 in_flight = None
@@ -211,25 +225,39 @@ class AsyncEngine:
                     # collection
                     plan2 = eng._plan_ahead(plan)
                 if plan2 is not None:
-                    toks_dev = steps.greedy_tokens(logits)
+                    with (eng.obs.span("serve/argmax")
+                          if eng.obs is not None else _NULL_CTX):
+                        toks_dev = steps.greedy_tokens(logits)
                     logits2 = eng._decode_dispatch(plan2, device_toks=toks_dev)
-                    self.stats["ahead_ticks"] += 1
                     # pull tick N's tokens to host while tick N+1 computes
-                    # analyze: allow[host-sync] the acknowledged sync: overlapped with the in-flight tick
-                    toks_host = np.asarray(toks_dev)[:, 0]
+                    with (eng.obs.span("serve/token_pull")
+                          if eng.obs is not None else _NULL_CTX):
+                        # analyze: allow[host-sync] the acknowledged sync: overlapped with the in-flight tick
+                        toks_host = np.asarray(toks_dev)[:, 0]
                     eng._decode_collect(plan, logits, toks_host=toks_host)
                     in_flight = (plan2, logits2)
                 else:
                     eng._decode_collect(plan, logits)
                 eng._finish_tick()
-                self.stats["ticks"] += 1
                 self._deliver()
-                await asyncio.sleep(0)
+                await self._yield()
         except BaseException as e:
             self._fail(e)
             raise
         finally:
             self._deliver()
+            if eng.obs is not None:
+                eng._host_bound_close()
+                if drained:
+                    self._idle = eng.obs.overlay("serve/pump_idle")
+
+    async def _yield(self) -> None:
+        """Hand the loop to consumers and the load generator for one turn."""
+        if self.engine.obs is None:
+            await asyncio.sleep(0)
+            return
+        with self.engine.obs.span("serve/yield"):
+            await asyncio.sleep(0)
 
     def _apply_cancels(self) -> None:
         q, self._cancel_q = self._cancel_q, []
@@ -238,18 +266,20 @@ class AsyncEngine:
 
     def _deliver(self) -> None:
         """Push newly emitted tokens (and completions) to consumer queues."""
-        finished = []
-        for rid, handle in self._handles.items():
-            out = handle.req.out_tokens
-            while handle._n_sent < len(out):
-                handle._queue.put_nowait(out[handle._n_sent])
-                handle._n_sent += 1
-            if handle.req.done:
-                handle._queue.put_nowait(_DONE)
-                handle._done.set()
-                finished.append(rid)
-        for rid in finished:
-            del self._handles[rid]
+        obs = self.engine.obs
+        with (obs.span("serve/deliver") if obs is not None else _NULL_CTX):
+            finished = []
+            for rid, handle in self._handles.items():
+                out = handle.req.out_tokens
+                while handle._n_sent < len(out):
+                    handle._queue.put_nowait(out[handle._n_sent])
+                    handle._n_sent += 1
+                if handle.req.done:
+                    handle._queue.put_nowait(_DONE)
+                    handle._done.set()
+                    finished.append(rid)
+            for rid in finished:
+                del self._handles[rid]
 
     def _fail(self, error: BaseException) -> None:
         """Propagate a pump failure to every live consumer."""

@@ -17,6 +17,8 @@ linears go INT4 (w4a16), params are TP-sharded over ``model`` only (no FSDP
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -112,8 +114,11 @@ def greedy_tokens(logits):
     return _greedy_tokens(logits)
 
 
+_NULL_CTX = contextlib.nullcontext()
+
+
 def chunked_prefill(prefill_chunk_fn, params, state, prompts, *, chunk: int,
-                    on_chunk=None):
+                    span=None):
     """Prefill several prompts through repeated fixed-width chunk calls.
 
     prompts: list of ``slots`` token lists — row *i* is decode slot *i*;
@@ -124,9 +129,10 @@ def chunked_prefill(prefill_chunk_fn, params, state, prompts, *, chunk: int,
     shape.  Returns (last_logits (slots, V) f32 — garbage for idle rows —
     and the updated state).
 
-    ``on_chunk(chunk_index, n_chunks)``, when given, is called after each
-    chunk dispatch (the engine's obs layer emits ``prefill_chunk`` trace
-    events through it; ``None`` — the default — costs nothing).
+    ``span(chunk_index, n_chunks)``, when given, returns a context manager
+    entered around each chunk call (the engine's obs layer times
+    ``serve/prefill_chunk`` and emits ``prefill_chunk`` events through it;
+    ``None`` — the default — costs nothing).
     """
     b = len(prompts)
     lens = [len(p) if p else 0 for p in prompts]
@@ -141,10 +147,10 @@ def chunked_prefill(prefill_chunk_fn, params, state, prompts, *, chunk: int,
     last = [None] * b
     for c in range(n_chunks):
         sl = slice(c * chunk, (c + 1) * chunk)
-        logits, state = prefill_chunk_fn(params, state, jnp.asarray(toks[:, sl]),
-                                         jnp.asarray(pos[:, sl]))
-        if on_chunk is not None:
-            on_chunk(c, n_chunks)
+        with (span(c, n_chunks) if span is not None else _NULL_CTX):
+            logits, state = prefill_chunk_fn(params, state,
+                                             jnp.asarray(toks[:, sl]),
+                                             jnp.asarray(pos[:, sl]))
         for i, n in enumerate(lens):
             if n and c * chunk <= n - 1 < (c + 1) * chunk:
                 last[i] = logits[i, (n - 1) % chunk]

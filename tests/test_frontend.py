@@ -7,7 +7,7 @@ to generating it alone through ``model.prefill`` + ``model.decode_step``
 (the same reference the synchronous scheduler fuzz pins), and every
 cancelled request must hold a strict greedy prefix.  The satellites pin the
 submit-time validation, drained-engine reuse, deadline expiry, and that
-dispatch-ahead actually engages (``stats["ahead_ticks"]``).
+dispatch-ahead actually engages (``serve_ahead_ticks_total``).
 
 Tests drive the event loop with ``asyncio.run`` inside ordinary sync test
 functions — no asyncio pytest plugin required.
@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 import test_serve_fuzz as fuzz
 
+from repro.obs import Observer
 from repro.serve import AsyncEngine
 from repro.serve.engine import Engine
 
@@ -119,15 +120,16 @@ def test_dispatch_ahead_engages_and_matches_reference():
 
     async def scenario():
         fe = AsyncEngine(model, params, slots=2, max_len=96, block_size=8,
-                         prefill_chunk=8)
+                         prefill_chunk=8, obs=Observer())
         toks = [t async for t in fe.submit(prompt, max_tokens=n).stream()]
         await fe.drain()
-        return toks, fe.stats
+        return toks, fe.engine.obs.registry
 
-    toks, stats = asyncio.run(scenario())
+    toks, reg = asyncio.run(scenario())
     assert toks == expected
-    assert stats["ahead_ticks"] > 0, "dispatch-ahead never engaged"
-    assert stats["ahead_ticks"] <= stats["ticks"]
+    ahead = reg.get("serve_ahead_ticks_total").value
+    assert ahead > 0, "dispatch-ahead never engaged"
+    assert ahead <= reg.get("serve_decode_ticks_total").value
 
 
 def test_cancel_mid_stream_keeps_prefix_and_frees_slot():

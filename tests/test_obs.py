@@ -355,7 +355,7 @@ def test_disabled_obs_identical_tokens_ticks_and_syncs(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# kernels.dispatch counters, resolved_backend, kernel timing
+# kernels.dispatch counters, resolved_backend
 # ---------------------------------------------------------------------------
 def test_dispatch_counts_and_resolved_backend():
     dispatch.reset_dispatch_metrics()
@@ -377,24 +377,6 @@ def test_dispatch_counts_and_resolved_backend():
     f(x)
     f(x)  # cached executions re-run nothing at trace level
     assert dispatch.dispatch_counts()[("probe", "xla")] == 1
-
-
-def test_dispatch_kernel_timing_env(monkeypatch):
-    dispatch.reset_dispatch_metrics()
-    monkeypatch.setenv("REPRO_OBS_KERNEL_TIMING", "1")
-    x = jnp.ones((2, 8), jnp.float32)
-    w = jnp.ones((8, 4), jnp.float32)
-    dispatch.dense_linear(x, w, role="timed")
-    h = dispatch.kernel_metrics().get("kernel_wall_seconds", role="timed",
-                                      backend="xla")
-    assert h is not None and h.count == 1 and h.vmax > 0
-    # under a jit trace the inputs are Tracers: the fence must NOT fire
-    jax.jit(lambda a: dispatch.dense_linear(a, w, role="timed"))(x)
-    assert h.count == 1
-    monkeypatch.delenv("REPRO_OBS_KERNEL_TIMING")
-    dispatch.dense_linear(x, w, role="timed")
-    assert h.count == 1  # timing off again
-    dispatch.reset_dispatch_metrics()
 
 
 def test_engine_records_prefill_dispatch():
