@@ -19,13 +19,17 @@ Every model family serves through the same two-piece contract:
   engine consumes::
 
       init_state()                                     -> state pytree
-      prefill_chunk(params, state, tokens, positions)  -> (logits (B,C,V), state)
+      prefill_chunk(params, state, tokens, positions[, slots])
+                                                       -> (logits (B,C,V), state)
       decode_step(params, state, tokens, positions)    -> (logits (B,V),  state)
 
   ``tokens``/``positions`` follow one convention everywhere: rows are decode
   slots, positions are per-sequence absolute token indices, and ``-1`` marks
   padding/inactive rows, so a single fixed-shape program covers every
-  schedule state (ragged batches, mixed prefill progress, idle slots).
+  schedule state (ragged batches, mixed prefill progress, idle slots).  A
+  backend whose only per-slot state is its block table also takes
+  ``slots``, one slot id per prefill row: the tile then holds only the rows
+  being prefilled.
 
 Capabilities are **declared**, not probed: :data:`FAMILY_BACKENDS` is the
 family × backend matrix, and :func:`make_session` raises a
@@ -62,10 +66,12 @@ def canonical_cache_dtype(dtype) -> str:
 class SessionSpec:
     """Static geometry of one serving session.
 
-    ``slots`` is the decode-batch width (prefill rows are slots too — an
-    admitted request prefills *in its slot*, idle rows ride along at
-    position ``-1``).  ``num_blocks`` defaults to full occupancy plus the
-    reserved null block for block-pool backends.
+    ``slots`` is the decode-batch width.  An admitted request prefills *in
+    its slot*: as its row of a (slots, chunk) tile with idle rows riding
+    along at position ``-1``, or, where the block table is the only
+    per-slot state, as a row of a tile of the admitted requests alone.
+    ``num_blocks`` defaults to full occupancy plus the reserved null block
+    for block-pool backends.
     """
     slots: int
     max_len: int
@@ -117,7 +123,11 @@ class InferenceSession:
         raise NotImplementedError
 
     def prefill_chunk(self, params, state, tokens, positions):
-        """tokens (B,C), positions (B,C) -> (logits (B,C,V) f32, state)."""
+        """tokens (B,C), positions (B,C) -> (logits (B,C,V) f32, state).
+
+        Rows are the decode slots in order.  A backend whose only per-slot
+        state is its block table takes a fifth argument ``slots`` (B,) int32,
+        the slot each row prefills for (default: all slots, in order)."""
         raise NotImplementedError
 
     def decode_step(self, params, state, tokens, positions):
@@ -148,9 +158,12 @@ class PagedKVSession(InferenceSession):
             "block_tables": jnp.zeros((sp.slots, sp.table_width()), jnp.int32),
         }
 
-    def prefill_chunk(self, params, state, tokens, positions):
+    def prefill_chunk(self, params, state, tokens, positions, slots=None):
+        tables = state["block_tables"]
+        if slots is not None:  # a tile of the admitted rows: their tables
+            tables = tables[slots]
         logits, kv = transformer.prefill_paged_chunk(
-            params, self.cfg, state["kv"], tokens, state["block_tables"], positions)
+            params, self.cfg, state["kv"], tokens, tables, positions)
         return logits, dict(state, kv=kv)
 
     def decode_step(self, params, state, tokens, positions):

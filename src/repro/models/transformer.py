@@ -508,7 +508,11 @@ def prefill_paged_chunk(params, cfg: ModelConfig, caches, tokens, block_tables,
     """One chunk of batched prefill, writing K/V straight into paged blocks.
 
     tokens: (B, C); positions: (B, C) absolute positions (``-1`` = padding —
-    prompts shorter than the chunk grid).  Earlier chunks must already be
+    prompts shorter than the chunk grid, or rows with no prompt);
+    block_tables: (B, W), row *i* the table of the sequence row *i*
+    prefills.  Rows need not be decode slots: a row's slot matters only
+    through its table, so a tile of just the admitted sequences computes
+    the same as the (slots, C) tile.  Earlier chunks must already be
     written (the serve driver ``serve.steps.chunked_prefill`` guarantees
     order).  Returns logits (B, C, V) f32 for *every* chunk position — the
     driver picks each sequence's last-real-token row — and updated caches.

@@ -65,6 +65,7 @@ _COMMON = {"ev": str, "t": (int, float), "seq": int}
 _FIELD_TYPES = {
     "rid": int, "slot": int, "tick": int, "prompt_len": int,
     "max_tokens": int, "n_tokens": int, "chunk": int, "n_chunks": int,
+    "tile_rows": int, "real_rows": int,
     "rids": list, "ttft_s": (int, float), "active": int, "reason": str,
     "n_out": int, "utilization": (int, float), "free_blocks": int,
     "live_tokens": int, "active_slots": int, "deadline_s": (int, float),

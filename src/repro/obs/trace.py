@@ -28,7 +28,8 @@ import time
 EVENT_FIELDS: dict[str, tuple[str, ...]] = {
     "submit": ("rid", "prompt_len", "max_tokens"),
     "admit": ("rid", "slot", "tick", "n_tokens"),
-    "prefill_chunk": ("tick", "chunk", "n_chunks", "rids"),
+    "prefill_chunk": ("tick", "chunk", "n_chunks", "rids", "tile_rows",
+                      "real_rows"),
     "first_token": ("rid", "tick", "ttft_s"),
     "decode_tick": ("tick", "active"),
     "preempt": ("rid", "slot", "tick"),
@@ -44,8 +45,8 @@ EVENT_FIELDS: dict[str, tuple[str, ...]] = {
     "compile": ("fun", "stage", "dur", "sid"),
 }
 # fields a span carries where they apply, checked by type when present
-SPAN_FIELDS = ("rids", "n_tokens", "chunk", "n_chunks", "tick", "active",
-               "ahead", "starved")
+SPAN_FIELDS = ("rids", "n_tokens", "chunk", "n_chunks", "tile_rows",
+               "real_rows", "tick", "active", "ahead", "starved")
 
 _seq = itertools.count()
 _sid = itertools.count()

@@ -6,9 +6,11 @@ drives the jitted programs built by ``serve.steps.session_step_fns`` from an
 layout (paged K/V blocks, per-slot rings, recurrent state, encoder context)
 is entirely the backend's business.  The scheduler sees one uniform surface:
 
-* ``prefill_chunk(params, state, tokens, positions)`` — rows are decode
-  slots; admitted prompts prefill *batched* in fixed-width chunks while idle
-  slots ride along at position ``-1``.
+* ``prefill_chunk(params, state, tokens, positions[, slots])`` — admitted
+  prompts prefill *batched* in fixed-width chunks.  Where the block table is
+  the only per-slot state (paged sessions) the tile is ``min(prefill_batch,
+  slots)`` rows of admitted prompts, each naming its slot; otherwise its
+  rows are the decode slots and idle slots ride along at position ``-1``.
 * ``decode_step(params, state, tokens, positions)`` — one call per tick
   regardless of position raggedness (per-sequence positions).
 
@@ -44,10 +46,12 @@ Observability (DESIGN.md §9): pass ``obs=`` an
 :class:`~repro.obs.Observer` / :class:`~repro.obs.ObsConfig` (or set
 ``REPRO_OBS=1``) and the engine emits structured scheduler events
 (admit / prefill_chunk / decode_tick / preempt / finish / pool_sample),
-queue-time / TTFT / inter-token latency histograms, and block-pool
-utilization gauges, and times each stage in a span: ``serve/admit`` (with
-``serve/prefill_chunk``, ``serve/prefill_wait`` and ``serve/first_sample``
-inside), ``serve/decode_schedule``, ``serve/decode_dispatch`` (``ahead``,
+queue-time / TTFT / inter-token latency histograms, block-pool
+utilization gauges and the prefill tile's rows against its rows that hold
+tokens (``serve_prefill_tile_rows_total`` / ``serve_prefill_real_rows_total``),
+and times each stage in a span: ``serve/admit`` (with
+``serve/prefill_chunk`` (``tile_rows``, ``real_rows``), ``serve/prefill_wait``
+and ``serve/first_sample`` inside), ``serve/decode_schedule``, ``serve/decode_dispatch`` (``ahead``,
 ``starved``), ``serve/decode_collect``, and the overlay
 ``serve/host_bound``: from a sync that left no device work in flight while
 requests are live until the next dispatch has enqueued its device work.
@@ -79,6 +83,11 @@ from . import steps
 from .kv_cache import BlockManager, blocks_for, pack_block_tables
 
 _NULL_CTX = contextlib.nullcontext()  # reusable no-op span (obs disabled)
+
+
+def _leaf_name(path) -> str:
+    """Key of a state leaf within its parent (``"block_tables"``, ``"k"``)."""
+    return str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
 
 
 @dataclass
@@ -216,6 +225,15 @@ class Engine:
                                         spec.block_size)
         self.state = self.session.init_state()
         self._batch_axis = self._find_batch_axes()
+        # where the block table is the only per-slot state, a prefill tile
+        # holds just the admitted rows (each names its slot); otherwise it
+        # spans every slot (None), since compacting would need a gather and
+        # a scatter of the per-slot state rows
+        compact = all(axis < 0 or _leaf_name(path) == "block_tables"
+                      for path, axis in
+                      jax.tree_util.tree_leaves_with_path(self._batch_axis))
+        self._prefill_rows = (min(self.prefill_batch, self.slots) if compact
+                              else None)
         self.queue: list[Request] = []
         self.finished: list[Request] = []
         self.admission = admission if admission is not None else FCFSAdmission()
@@ -244,6 +262,8 @@ class Engine:
             self._c_cancel = reg.counter("serve_cancellations_total")
             self._c_deadline = reg.counter("serve_deadline_miss_total")
             self._c_ahead = reg.counter("serve_ahead_ticks_total")
+            self._c_tile_rows = reg.counter("serve_prefill_tile_rows_total")
+            self._c_real_rows = reg.counter("serve_prefill_real_rows_total")
             self._g_active = reg.gauge("serve_active_slots")
             if self.manager is not None:
                 self._g_util = reg.gauge("serve_pool_utilization")
@@ -482,8 +502,7 @@ class Engine:
         m = jnp.asarray(mask)
 
         def upd(path, leaf, axis):
-            name = str(getattr(path[-1], "key", getattr(path[-1], "idx", "")))
-            if axis < 0 or name == "block_tables":
+            if axis < 0 or _leaf_name(path) == "block_tables":
                 return leaf
             mb = m.reshape((1,) * axis + (self.slots,) + (1,) * (leaf.ndim - axis - 1))
             fill = -1 if leaf.dtype == jnp.int32 else 0
@@ -548,26 +567,44 @@ class Engine:
 
     def _prefill_batch(self, batch: list[tuple[int, Request]]) -> None:
         """Prefill ``batch`` in chunks, stamp first tokens and sample them."""
+        seqs = [self._seq_tokens(req) for _, req in batch]
+        if self._prefill_rows is None:  # the (slots, chunk) tile: row = slot
+            rows = [s for s, _ in batch]
+            prompts: list[list[int] | None] = [None] * self.slots
+            for s, seq in zip(rows, seqs):
+                prompts[s] = seq
+            slot_ids = None
+        else:  # the admitted rows only; padding rows name slot 0
+            pad = self._prefill_rows - len(batch)
+            rows = list(range(len(batch)))
+            prompts = seqs + [None] * pad
+            slot_ids = [s for s, _ in batch] + [0] * pad
         chunk_span = None
         if self.obs is not None:
             t_admit = time.perf_counter()
-            for s, req in batch:
+            for (s, req), seq in zip(batch, seqs):
                 self.obs.event("admit", t=t_admit, rid=req.rid, slot=s,
-                               tick=self._tick_no,
-                               n_tokens=len(self._seq_tokens(req)))
+                               tick=self._tick_no, n_tokens=len(seq))
                 if not req.t_first:  # first admission, not a preempt replay
                     self._h_queue.observe(t_admit - req.t_submit)
             rids = [req.rid for _, req in batch]
+            tile_rows = len(prompts)
 
             @contextlib.contextmanager
             def chunk_span(c, n_chunks):
+                real_rows = sum(len(seq) > c * self.prefill_chunk
+                                for seq in seqs)
+                self._c_tile_rows.inc(tile_rows)
+                self._c_real_rows.inc(real_rows)
                 with self.obs.span("serve/prefill_chunk", chunk=c,
-                                   n_chunks=n_chunks):
+                                   n_chunks=n_chunks, tile_rows=tile_rows,
+                                   real_rows=real_rows):
                     yield
                     if c == 0:  # the device has work: host-bound ends
                         self._host_bound_close()
                 self.obs.event("prefill_chunk", tick=self._tick_no, chunk=c,
-                               n_chunks=n_chunks, rids=rids)
+                               n_chunks=n_chunks, rids=rids,
+                               tile_rows=tile_rows, real_rows=real_rows)
         self._reset_slots([s for s, _ in batch])
         if self.session.needs_encoder_ctx:
             for s, req in batch:
@@ -578,12 +615,9 @@ class Engine:
                 self.state = self._begin(self.params, self.state, jnp.int32(s),
                                          jnp.asarray(frames)[None])
         self._sync_tables(extra={s: req.rid for s, req in batch})
-        prompts: list[list[int] | None] = [None] * self.slots
-        for s, req in batch:
-            prompts[s] = self._seq_tokens(req)
         logits, self.state = steps.chunked_prefill(
             self._prefill, self.params, self.state, prompts,
-            chunk=self.prefill_chunk, span=chunk_span)
+            chunk=self.prefill_chunk, slots=slot_ids, span=chunk_span)
         with (self.obs.span("serve/prefill_wait") if self.obs is not None
               else _NULL_CTX):
             # first-token latency: stamp only after the device finishes
@@ -593,7 +627,7 @@ class Engine:
             self._host_bound_open()
         with (self.obs.span("serve/first_sample") if self.obs is not None
               else _NULL_CTX):
-            for s, req in batch:
+            for (s, req), row, seq in zip(batch, rows, seqs):
                 fresh = not req.t_first
                 if fresh:
                     req.t_first = t_ready
@@ -603,14 +637,14 @@ class Engine:
                                        tick=self._tick_no,
                                        ttft_s=t_ready - req.t_submit)
                 self._t_last_tok[s] = t_ready
-                tok = self._sample(logits[s])
+                tok = self._sample(logits[row])
                 if self._emit(req, tok):  # eos on first token / max_tokens=1
                     self._t_last_tok.pop(s, None)
                     if self.manager is not None:
                         self.manager.free(req.rid)
                     continue
                 self.slot_req[s] = req
-                self.slot_pos[s] = len(prompts[s])
+                self.slot_pos[s] = len(seq)
                 self._admit_order.append(s)
         if self.obs is not None and not self.pending():
             self._host_bound_close()

@@ -8,7 +8,8 @@ are memoized per (session type, model config, kernel backend) so every
 :class:`~repro.serve.engine.Engine` over the same model shares one trace
 cache (the scheduler fuzz suite builds dozens of engines).  The
 ``chunked_prefill`` driver feeds several waiting prompts through repeated
-fixed-width chunk calls of that one program.
+fixed-width chunk calls of that one program — on a tile of only the
+admitted rows where the session's sole per-slot state is its block table.
 
 Sharding rules (the paper's deployment path): TTD stays on, all non-TT
 linears go INT4 (w4a16), params are TP-sharded over ``model`` only (no FSDP
@@ -77,10 +78,12 @@ def session_step_fns(session: InferenceSession, kernel_backend: str | None = Non
     if key not in _STEP_CACHE:
         while len(_STEP_CACHE) >= 64:  # bounded like the old lru_cache
             _STEP_CACHE.pop(next(iter(_STEP_CACHE)))
-        def _prefill(params, state, tokens, positions, _s=session,
-                     _kb=kernel_backend):
+        def _prefill(params, state, tokens, positions, slots=None,
+                     _s=session, _kb=kernel_backend):
+            slot_arg = () if slots is None else (slots,)
             with backend_override(_kb):
-                return _s.prefill_chunk(params, state, tokens, positions)
+                return _s.prefill_chunk(params, state, tokens, positions,
+                                        *slot_arg)
 
         def _decode(params, state, tokens, positions, _s=session,
                     _kb=kernel_backend):
@@ -118,16 +121,20 @@ _NULL_CTX = contextlib.nullcontext()
 
 
 def chunked_prefill(prefill_chunk_fn, params, state, prompts, *, chunk: int,
-                    span=None):
+                    slots=None, span=None):
     """Prefill several prompts through repeated fixed-width chunk calls.
 
-    prompts: list of ``slots`` token lists — row *i* is decode slot *i*;
-    ``None``/empty rows are idle slots riding along at position ``-1`` (their
-    writes are dropped / routed to the null block by every backend).  Every
-    call processes a (slots, chunk) tile, so multiple admitted prompts
-    prefill together in ``ceil(longest/chunk)`` jitted calls of one static
-    shape.  Returns (last_logits (slots, V) f32 — garbage for idle rows —
-    and the updated state).
+    prompts: one token list per tile row; ``None``/empty rows are padding
+    riding along at position ``-1`` (their writes are dropped / routed to the
+    null block by every backend).  Without ``slots`` the tile is the whole
+    decode batch: row *i* is slot *i*.  With ``slots`` (one slot id per row)
+    the tile holds only the admitted rows, and each call passes the ids on
+    as the program's fifth positional argument, so the program reads that
+    slot's state (its block-table row).  Every call processes a
+    (rows, chunk) tile, so the admitted prompts prefill together in
+    ``ceil(longest/chunk)`` jitted calls of one static shape.  Returns
+    (last_logits (rows, V) f32 — zeros for padding rows — and the updated
+    state).
 
     ``span(chunk_index, n_chunks)``, when given, returns a context manager
     entered around each chunk call (the engine's obs layer times
@@ -144,17 +151,18 @@ def chunked_prefill(prefill_chunk_fn, params, state, prompts, *, chunk: int,
         if p:
             toks[i, :len(p)] = p
             pos[i, :len(p)] = np.arange(len(p))
+    slot_arg = () if slots is None else (jnp.asarray(slots, jnp.int32),)
     last = [None] * b
     for c in range(n_chunks):
         sl = slice(c * chunk, (c + 1) * chunk)
         with (span(c, n_chunks) if span is not None else _NULL_CTX):
             logits, state = prefill_chunk_fn(params, state,
                                              jnp.asarray(toks[:, sl]),
-                                             jnp.asarray(pos[:, sl]))
+                                             jnp.asarray(pos[:, sl]), *slot_arg)
         for i, n in enumerate(lens):
             if n and c * chunk <= n - 1 < (c + 1) * chunk:
                 last[i] = logits[i, (n - 1) % chunk]
-    # idle rows (including the all-empty batch, whose single chunk ran at
+    # padding rows (including the all-empty batch, whose single chunk ran at
     # position -1 with every write dropped) get a zero-logits row
     zero = jnp.zeros(logits.shape[-1], logits.dtype)
     return jnp.stack([x if x is not None else zero for x in last]), state

@@ -281,3 +281,66 @@ def test_run_returns_only_new_finishes_after_drain():
     assert [r.rid for r in done2] == [second.rid]
     assert second.out_tokens == _ref_generate(model, params, [4, 5, 6], 3)
     assert len(eng.finished) == 2  # cumulative history still intact
+
+
+# ---------------------------------------------------------------------------
+# Prefill tile: the admitted rows alone where the block table is the only
+# per-slot state, every slot otherwise
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("arch,backend,slots,prefill_batch,rows", [
+    ("tinyllama-1.1b", "paged", 4, 2, 2),
+    ("tinyllama-1.1b", "paged", 2, 3, 2),
+    ("tinyllama-1.1b", "ring", 4, 2, 4),
+    ("rwkv6-7b", "recurrent", 4, 2, 4),
+    ("whisper-base", "encdec", 4, 2, 4),
+])
+def test_prefill_tile_rows_follow_the_per_slot_state(arch, backend, slots,
+                                                     prefill_batch, rows):
+    """Each chunk call gets a (rows, chunk) tile, rows = min(prefill_batch,
+    slots) on a paged session and ``slots`` where other state is per slot;
+    an admission makes ceil(longest/chunk) calls; and the paged engine
+    emits the greedy tokens the (slots, chunk) tile gives."""
+    cfg = get_config(arch, reduced=True).replace(
+        compute_dtype="float32", param_dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    chunk = 8
+    kw = dict(slots=slots, max_len=64, block_size=4, backend=backend,
+              prefill_batch=prefill_batch, prefill_chunk=chunk)
+    work = [(5, 4), (19, 6), (9, 3), (3, 5), (12, 2), (26, 4)]
+    prompts = [[(7 * i + 3 * j) % (cfg.vocab_size - 1) + 1 for j in range(n)]
+               for i, (n, _) in enumerate(work)]
+
+    eng = Engine(model, params, **kw)
+    tiles, admissions = [], []
+    program = eng._prefill
+
+    def prefill_chunk(*args):  # positional only, as the benchmark wraps it
+        tiles.append(tuple(args[2].shape))
+        return program(*args)
+
+    eng._prefill = prefill_chunk
+    prefill_batch_fn = eng._prefill_batch
+
+    def recording_prefill_batch(batch):
+        lens = [len(r.prompt) + len(r.out_tokens) for _, r in batch]
+        before = len(tiles)
+        prefill_batch_fn(batch)
+        admissions.append((lens, len(tiles) - before))
+
+    eng._prefill_batch = recording_prefill_batch
+    reqs = [eng.submit(p, max_tokens=m) for p, (_, m) in zip(prompts, work)]
+    eng.run()
+    assert all(r.done and len(r.out_tokens) == m
+               for r, (_, m) in zip(reqs, work))
+    assert tiles and set(tiles) == {(rows, chunk)}
+    assert admissions and all(n == -(-max(lens) // chunk)
+                              for lens, n in admissions)
+    assert sum(len(lens) for lens, _ in admissions) == len(work)
+
+    if backend == "paged":
+        full = Engine(model, params, **kw)
+        full._prefill_rows = None  # the (slots, chunk) tile
+        ref = [full.submit(p, max_tokens=m) for p, (_, m) in zip(prompts, work)]
+        full.run()
+        assert [r.out_tokens for r in reqs] == [r.out_tokens for r in ref]

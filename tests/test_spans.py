@@ -265,3 +265,26 @@ def test_recompile_names_its_function_and_span():
     prefill = [e for e in compiles if e["fun"] == "_prefill" and e["stage"] == "compile"]
     assert all(by_sid[e["sid"]]["name"] == "serve/prefill_chunk" for e in prefill)
     assert obs.registry.get("serve_compiles_total", fun="_decode").value >= 1
+
+
+@pytest.mark.parametrize("backend,tile_rows", [("paged", 2), ("ring", 4)])
+def test_prefill_chunk_counts_tile_and_real_rows(backend, tile_rows):
+    """``serve/prefill_chunk`` spans and ``prefill_chunk`` events carry the
+    tile's rows and the rows with tokens in that chunk, and the two
+    registry counters add them up."""
+    model, params, _ = fuzz._setup("dense")
+    obs = Observer()
+    eng = Engine(model, params, slots=4, max_len=96, block_size=8,
+                 prefill_chunk=8, prefill_batch=2, backend=backend, obs=obs)
+    eng.submit(list(range(1, 6)), max_tokens=2)    # 5 tokens: chunk 0
+    eng.submit(list(range(1, 20)), max_tokens=2)   # 19 tokens: chunks 0-2
+    eng.run()
+    assert validate_events(obs.trace.events) == []
+    events = obs.trace.by_type("prefill_chunk")
+    spans = _spans(obs, "serve/prefill_chunk")
+    for recs in (events, spans):
+        assert [(e["chunk"], e["tile_rows"], e["real_rows"]) for e in recs] \
+            == [(0, tile_rows, 2), (1, tile_rows, 1), (2, tile_rows, 1)]
+    reg = obs.registry
+    assert reg.get("serve_prefill_tile_rows_total").value == 3 * tile_rows
+    assert reg.get("serve_prefill_real_rows_total").value == 4

@@ -21,6 +21,7 @@ from repro.kernels.prefill_attention import prefill_attention_pallas
 from repro.kernels.tt_linear import tt_linear_pallas
 
 SLOTS, CHUNK = 4, 256
+PREFILL_BATCH = 2  # rows of a paged prefill tile: the admitted prompts
 POOL_BLOCKS, BLOCK = 4096, 16  # 32 MiB of bf16 K per layer: no VMEM holds it
 TABLE_WIDTH = 2048 // BLOCK
 
@@ -55,7 +56,7 @@ def _compile(fn, *shapes, sharding):
     assert "tpu_custom_call" in text  # the Mosaic kernel, not an interpreter
 
 
-@pytest.mark.parametrize("rows", [8, 256])
+@pytest.mark.parametrize("rows", [8, 256, PREFILL_BATCH * CHUNK])
 @pytest.mark.parametrize("role", [r for r, _ in TT_OVERRIDES])
 def test_tt_linear_compiles_for_v5e(one_chip, role, rows):
     ov = dict(TT_OVERRIDES)[role]
@@ -90,13 +91,22 @@ def test_paged_attention_compiles_for_v5e(one_chip):
              sharding=one_chip)
 
 
-def test_prefill_attention_compiles_for_v5e(one_chip):
+def _compile_prefill_attention(rows, sharding):
     cfg, pool = _pool()
 
     def fn(q, qpos, k, v, bt):
         return prefill_attention_pallas(q, qpos, cache={"k": k, "v": v},
                                         block_tables=bt, interpret=False)
 
-    _compile(fn, ((SLOTS, CHUNK, cfg.n_heads, cfg.head_dim), jnp.bfloat16),
-             ((SLOTS, CHUNK), jnp.int32), pool, pool,
-             ((SLOTS, TABLE_WIDTH), jnp.int32), sharding=one_chip)
+    _compile(fn, ((rows, CHUNK, cfg.n_heads, cfg.head_dim), jnp.bfloat16),
+             ((rows, CHUNK), jnp.int32), pool, pool,
+             ((rows, TABLE_WIDTH), jnp.int32), sharding=sharding)
+
+
+def test_prefill_attention_compiles_for_v5e(one_chip):
+    _compile_prefill_attention(SLOTS, one_chip)
+
+
+def test_prefill_attention_compiles_for_v5e_admitted_rows(one_chip):
+    """The paged prefill tile: ``PREFILL_BATCH`` rows, one per admitted prompt."""
+    _compile_prefill_attention(PREFILL_BATCH, one_chip)
