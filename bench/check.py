@@ -17,7 +17,6 @@ float8 computation ranks first.
 from __future__ import annotations
 
 import functools
-import importlib.util
 from pathlib import Path
 
 import jax
@@ -29,11 +28,8 @@ from traffic import longest_sequence, padded_length
 
 
 def load_reference(config_path: Path, cj: dict):
-    path = Path(config_path).parent / cj["reference"]
-    spec = importlib.util.spec_from_file_location(f"reference_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return bench_model.load_module(Path(config_path).parent / cj["reference"],
+                                   "reference")
 
 
 def draw_sample(records, seed: int, min_tokens: int, max_requests: int):

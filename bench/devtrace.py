@@ -16,14 +16,14 @@ that launched it: dispatches and executions keep their order, so the
 executions seen are a run of consecutive calls, and the pairing is the
 latest run whose every dispatch came before its execution started.  The
 pairing gives each kernel event the rows and context lengths it worked on,
-and :mod:`opcount` the operations and bytes they need.
+and the configuration's ops module (``KERNELS``, by default :mod:`opcount`)
+the operations and bytes they need.
 
 A device operation's name in the trace is its HLO instruction, shapes
-included, so a Mosaic kernel (``custom_call_target="tpu_custom_call"``) is
-told apart by its signature: an int32 block table first is attention (paged
-decode with a rank-3 result, chunked prefill with rank 4); the others are
-``tt_linear``, the only other kernel the served dense models run.  Its rows
-and widths come from the same text.
+included.  A Mosaic kernel (``custom_call_target="tpu_custom_call"``) takes
+its kind from the instruction's name, which is its ``pallas_call``'s
+(``%tt_linear.48 = ...`` is a ``tt_linear``); its rows and widths come from
+the same text.
 """
 from __future__ import annotations
 
@@ -32,6 +32,7 @@ import os
 import re
 import sys
 from collections import defaultdict
+from types import SimpleNamespace
 
 import numpy as np
 
@@ -40,6 +41,7 @@ import opcount
 TOL_S = 50e-6            # host and device clocks agree to this
 PROGRAMS = {"prefill": "jit__prefill", "decode": "jit__decode"}
 _SUFFIX = re.compile(r"[.\d]+$")
+_INSTR_SUFFIX = re.compile(r"(\.\d+)+$")
 
 
 # -- reading the trace ---------------------------------------------------------
@@ -152,7 +154,8 @@ def _braced(line: str, key: str) -> str:
 
 def parse_kernel(text: str) -> dict | None:
     """{"kind", "result", "operands"} of a Mosaic kernel's HLO instruction,
-    or ``None`` for any other instruction."""
+    or ``None`` for any other instruction.  ``kind`` is the instruction's
+    name without its numeric suffix."""
     if 'custom_call_target="tpu_custom_call"' not in text:
         return None
     m = _INSTR.match(text)
@@ -162,24 +165,14 @@ def parse_kernel(text: str) -> dict | None:
     operands = [(dt, tuple(int(x) for x in dims.split(",") if x))
                 for dt, dims in _SHAPE.findall(_braced(text, "operand_layout_constraints="))]
     result = tuple(int(x) for x in res.group(2).split(",") if x) if res else ()
-    if operands and operands[0][0] == "s32":
-        kind = "paged_attention" if len(result) == 3 else "prefill_attention"
-    else:
-        kind = "tt_linear"
-    return {"kind": kind, "result": result, "operands": operands}
+    return {"kind": _INSTR_SUFFIX.sub("", m.group(1)), "result": result,
+            "operands": operands}
 
 
 def short_name(text: str) -> str:
     """``%fusion.237 = bf16[...] fusion(...)`` -> ``fusion``."""
     head = text.split(" = ", 1)[0].strip().lstrip("%")
     return _SUFFIX.sub("", head) or head
-
-
-def _tt_role(tt: dict, n_in: int, n_out: int):
-    for role, t in tt.items():
-        if int(np.prod(t["in_modes"])) == n_in and int(np.prod(t["out_modes"])) == n_out:
-            return role
-    return None
 
 
 # -- the reduction -------------------------------------------------------------
@@ -230,34 +223,26 @@ def reduce_events(ev: dict, window, calls: dict, chips: int = 1) -> dict:
             "kernel_events": kev, "ops": ops}
 
 
-def roofline_of(kev, kind: str, tt: dict, model: dict, peaks: dict):
-    """(share of roofline in %, share of calls bound by memory) of ``kind``."""
+def roofline_of(kev, kind: str, tt: dict, model: dict, peaks: dict, *,
+                ops=opcount, events=None):
+    """(share of roofline in %, share of calls bound by memory) of ``kind``,
+    each call counted by ``ops.KERNELS[kind](event, run)``; ``None`` where
+    ``ops`` counts no such kernel, or cannot count one of its calls (it
+    returns ``None``), since a share of the other calls would be biased."""
+    count = getattr(ops, "KERNELS", {}).get(kind)
+    if count is None:
+        return None
+    run = SimpleNamespace(model=model, tt=tt, events=events)
     need = spent = 0.0
     mem_bound = n = 0
     for k in kev:
         if k["kind"] != kind:
             continue
-        if kind == "tt_linear":
-            rows, n_out = k["result"]
-            n_in = k["operands"][0][1][-1]
-            role = _tt_role(tt, n_in, n_out)
-            if role is None:
-                continue
-            ops, moved = opcount.tt_linear_call(
-                rows, tt[role], residual=role in ("attn_o", "mlp_down"))
-        elif kind == "paged_attention":
-            if k["call"] is None:
-                return None
-            ctx = [int(p) + 1 for p in k["call"]["positions"] if p >= 0]
-            ops, moved = opcount.paged_attention_call(
-                ctx, model["n_heads"], model["n_kv_heads"], model["head_dim"])
-        else:
-            if k["call"] is None:
-                return None
-            ops, moved = opcount.prefill_attention_call(
-                k["call"]["chunks"], model["n_heads"], model["n_kv_heads"],
-                model["head_dim"])
-        t_ops, t_mem = ops / peaks["bf16_flops"], moved / peaks["hbm_bytes_per_s"]
+        got = count(k, run)
+        if got is None:
+            return None
+        n_ops, moved = got
+        t_ops, t_mem = n_ops / peaks["bf16_flops"], moved / peaks["hbm_bytes_per_s"]
         need += max(t_ops, t_mem)
         mem_bound += t_mem >= t_ops
         spent += k["dur"]
@@ -267,11 +252,12 @@ def roofline_of(kev, kind: str, tt: dict, model: dict, peaks: dict):
     return 100.0 * need / spent, mem_bound / n
 
 
-def useful_flops(execs: dict, model: dict, tt: dict) -> float | None:
+def useful_flops(execs: dict, model: dict, tt: dict, ops=opcount) -> float | None:
     """Operations the served model needs for the tokens the traced
-    executions processed: every real prompt token through every block, one
-    unembedding per finished prompt, and every decoded token with its
-    unembedding (padding rows and the other prefill logits excluded)."""
+    executions processed (``ops.sequence_flops``): every real prompt token
+    through every block, one unembedding per finished prompt, and every
+    decoded token with its unembedding (padding rows and the other prefill
+    logits excluded)."""
     total = 0
     for prog, mods in execs.items():
         for m in mods:
@@ -280,10 +266,10 @@ def useful_flops(execs: dict, model: dict, tt: dict) -> float | None:
                 return None
             if prog == "prefill":
                 ctx = [s + i + 1 for s, n in call["chunks"] for i in range(n)]
-                total += opcount.sequence_flops(model, tt, ctx, call["finishing"])
+                total += ops.sequence_flops(model, tt, ctx, call["finishing"])
             else:
                 ctx = [int(p) + 1 for p in call["positions"] if p >= 0]
-                total += opcount.sequence_flops(model, tt, ctx, len(ctx))
+                total += ops.sequence_flops(model, tt, ctx, len(ctx))
     return float(total)
 
 
@@ -363,8 +349,8 @@ def program_ms(trace: dict | None, prog: str):
 def roofline(run, kind: str):
     if not run.trace:
         return None
-    got = roofline_of(run.trace["kernel_events"], kind, run.tt, run.cj["model"],
-                      run.peaks)
+    got = roofline_of(run.trace["kernel_events"], kind, run.tt, run.model,
+                      run.peaks, ops=run.ops, events=run.events)
     if got is None:
         return None
     share, mem = got
@@ -377,7 +363,7 @@ def roofline(run, kind: str):
 def mfu(run):
     if not run.trace:
         return None
-    flops = useful_flops(run.trace["execs"], run.cj["model"], run.tt)
+    flops = useful_flops(run.trace["execs"], run.model, run.tt, run.ops)
     if not flops or run.trace["window_s"] <= 0:
         return None
     return 100.0 * flops / (run.trace["window_s"] * run.peaks["bf16_flops"])

@@ -1,19 +1,25 @@
 """One run of one cell: set-up, the measured window, the check, the result.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration file, its traffic mix ``bench/traffic/<traffic>.json``,
-its correctness limit ``bench/limits/<cell>.json`` and one reader per
-quantity, ``bench/metrics/<quantity>.py``: a metric named ``<quantity>`` or
+its configuration file (with what it names beside it: the plain reference,
+and where it has one its ``ops`` module), its traffic mix
+``bench/traffic/<traffic>.json``, its correctness limit
+``bench/limits/<cell>.json`` and one reader per quantity,
+``bench/metrics/<quantity>.py``: a metric named ``<quantity>`` or
 ``<quantity>.<suffix>`` (the same quantity, split by the end-to-end metric it
 moves) is read by that file.  Unit, layer and what a metric moves are stated
-in ``BENCHMARK.json`` alone.  Adding a cell or a metric adds files and
-entries and edits none.
+in ``BENCHMARK.json`` alone.  What is particular to a model (its keys and
+TT roles, its weight rules, its operation counts) is read from those files
+(``model.py``); the session backend is the program's default for the
+model's family, and the attention roles that have to run on the Pallas
+kernels follow from it (``ATTENTION_ROLES``).  So adding a cell, a
+configuration of a served family, or a metric adds files and entries and
+edits none.
 """
 from __future__ import annotations
 
 import asyncio
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -28,6 +34,7 @@ import numpy as np
 
 import check
 import model as bench_model
+import opcount
 import serve as drivers
 import traffic as gen
 from peaks import peaks_for
@@ -52,13 +59,11 @@ class Run:
     events: list | None = None      # program's scheduler events (traced run)
     trace: dict | None = None       # reduced device trace (traced run)
     peaks: dict | None = None
+    ops: object = opcount           # the configuration's ops module
 
-
-def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(f"bench_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    @property
+    def model(self) -> dict:
+        return self.cj["model"]
 
 
 def cell_metrics(spec: dict, cell: str, kind: str) -> list[dict]:
@@ -75,7 +80,8 @@ def cell_metrics(spec: dict, cell: str, kind: str) -> list[dict]:
 def reader(entry: dict, root: Path):
     """The reader module of a metric: the file of its quantity, the part of
     its name before the first dot."""
-    return load_module(root / "bench" / "metrics" / f"{entry['name'].split('.')[0]}.py")
+    return bench_model.load_module(
+        root / "bench" / "metrics" / f"{entry['name'].split('.')[0]}.py")
 
 
 def dispatch_counts() -> dict:
@@ -84,19 +90,22 @@ def dispatch_counts() -> dict:
 
 
 _VERIFIED: dict = {}  # roles already checked in this process, per program
+# kernel roles the attention of each session backend dispatches
+ATTENTION_ROLES = {"paged": ("attn_paged", "attn_prefill")}
 
 
-def check_roles(cj: dict, expect: str, before: dict, key) -> list[str]:
-    """Roles that did not resolve to ``expect`` (or never dispatched) in the
-    traces since ``before``.  The programs trace once per process, so a run
-    that traced nothing new reuses the verdict of the run that did."""
+def check_roles(want: list[str], expect: str, before: dict, key) -> list[str]:
+    """Roles that did not resolve to ``expect`` in the traces since
+    ``before``: of ``want``, those that resolved elsewhere or never
+    dispatched, and any other that resolved to neither ``expect`` nor XLA.
+    The programs trace once per process, so a run that traced nothing new
+    reuses the verdict of the run that did."""
     roles: dict[str, set[str]] = {}
     for (role, backend), n in dispatch_counts().items():
         if n > before.get((role, backend), 0) and backend != "xla":
             roles.setdefault(role, set()).add(backend)
     if not roles and key in _VERIFIED:
         return _VERIFIED[key]
-    want = list(cj["model"]["ttd"]["roles"]) + ["attn_paged", "attn_prefill"]
     bad = sorted(r for r in want if roles.get(r) != {expect}) + \
         sorted(r for r, b in roles.items() if b != {expect} and r not in want)
     _VERIFIED[key] = bad
@@ -196,12 +205,14 @@ def _run(cell, cpath, cj, mix, e2e, layer, readers, peaks, seed, seconds,
     eng_geo = mix["engine"]
     cfg = bench_model.served_config(cj, kernel_backend=kernel_backend)
     model = build_model(cfg)
-    params = bench_model.make_params(model, cj, seed)
+    params = bench_model.make_params(
+        model, cj, seed,
+        leaf_rule=getattr(check.load_reference(cpath, cj), "leaf_rule", None))
     jax.block_until_ready(params)
     obs = Observer(ObsConfig(enabled=True)) if trace else False
     engine = drivers.RecordingEngine(
         model, params, slots=eng_geo["slots"], max_len=eng_geo["max_len"],
-        num_blocks=eng_geo["num_blocks"], backend="paged",
+        num_blocks=eng_geo["num_blocks"],
         prefill_chunk=eng_geo["prefill_chunk"],
         prefill_batch=eng_geo["prefill_batch"],
         cache_dtype=eng_geo["cache_dtype"], kernel_backend=kernel_backend,
@@ -210,13 +221,16 @@ def _run(cell, cpath, cj, mix, e2e, layer, readers, peaks, seed, seconds,
     vocab = cj["model"]["vocab_size"]
     before = dispatch_counts()
     asyncio.run(drivers.warm_up(front, gen.warmup_requests(mix, vocab)))
-    bad_roles = check_roles(cj, expect_backend, before,
+    want = list(bench_model.tt_roles(cj)) + list(
+        ATTENTION_ROLES.get(engine.session.backend, ()))
+    bad_roles = check_roles(want, expect_backend, before,
                             (cfg, tuple(sorted(eng_geo.items())), kernel_backend))
     engine.clear_records()
     if trace:
         obs.trace.events.clear()
 
     run = Run(cell=cell, cj=cj, mix=mix, tt=bench_model.tt_roles(cj),
+              ops=bench_model.ops_module(cpath, cj),
               seconds=seconds, setup_s=time.perf_counter() - t_start,
               peaks=peaks)
     tracer = _Tracer(seconds) if trace else None

@@ -1,7 +1,7 @@
 """Share of its roofline the ``tt_linear`` kernel reached in the traced
 window: the least time the chip needs for its calls (the larger of their
-operations over peak FLOP/s and their bytes over HBM bandwidth, from
-``opcount``) over the calls' device time."""
+operations over peak FLOP/s and their bytes over HBM bandwidth, from the
+configuration's ops module, ``KERNELS``) over the calls' device time."""
 import devtrace
 
 

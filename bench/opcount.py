@@ -5,6 +5,15 @@ lengths), never from how a kernel tiles it, so a roofline share reads the
 same work whatever implements it.  An operation is a multiply or an add
 (a multiply-accumulate counts 2).  Bytes are the least the call has to move
 between HBM and the chip: each input and output once.
+
+This is the default ops module of a configuration: a dense transformer with
+grouped-query attention, a SwiGLU MLP and TT linears on the roles of
+``tt``.  A configuration of another shape names its own module (``ops`` in
+its file) with the same two entries: ``sequence_flops(model, tt, contexts,
+with_unembed)`` and ``KERNELS``, ``{kind: fn(kernel_event, run) -> (ops,
+bytes) or None}``, where ``run`` has ``model``, ``tt`` and ``events`` (the
+program's event log); ``None`` says the call cannot be counted, and the
+kind then reads no roofline share.
 """
 from __future__ import annotations
 
@@ -81,7 +90,8 @@ def token_flops(model: dict, tt: dict, block: int, context: int,
     if unembed:
         return 2 * d * model["vocab_size"]
     q, kv, f = h * dh, hkv * dh, model["d_ff"]
-    is_tt = block >= model["ttd"]["first_tt_block"]
+    ttd = model.get("ttd")
+    is_tt = bool(ttd) and block >= ttd["first_tt_block"]
 
     def lin(role, n_in, n_out):
         if is_tt and role in tt:
@@ -103,3 +113,41 @@ def sequence_flops(model: dict, tt: dict, contexts, with_unembed: int) -> int:
     per_ctx = 4 * model["n_heads"] * model["head_dim"] * model["n_layers"]
     return (len(contexts) * fixed + per_ctx * sum(contexts)
             + with_unembed * token_flops(model, tt, 0, 0, True))
+
+
+# -- the kernels of the trace, counted per call --------------------------------
+def tt_role(tt: dict, n_in: int, n_out: int):
+    """The TT role of ``n_in`` inputs and ``n_out`` outputs, or ``None``."""
+    for role, t in tt.items():
+        if math.prod(t["in_modes"]) == n_in and math.prod(t["out_modes"]) == n_out:
+            return role
+    return None
+
+
+def _tt_linear(k: dict, run):
+    rows, n_out = k["result"]
+    role = tt_role(run.tt, k["operands"][0][1][-1], n_out)
+    if role is None:
+        return None
+    return tt_linear_call(rows, run.tt[role],
+                          residual=role in ("attn_o", "mlp_down"))
+
+
+def _paged_attention(k: dict, run):
+    if k["call"] is None:
+        return None
+    m = run.model
+    return paged_attention_call([int(p) + 1 for p in k["call"]["positions"] if p >= 0],
+                                m["n_heads"], m["n_kv_heads"], m["head_dim"])
+
+
+def _prefill_attention(k: dict, run):
+    if k["call"] is None:
+        return None
+    m = run.model
+    return prefill_attention_call(k["call"]["chunks"], m["n_heads"],
+                                  m["n_kv_heads"], m["head_dim"])
+
+
+KERNELS = {"tt_linear": _tt_linear, "paged_attention": _paged_attention,
+           "prefill_attention": _prefill_attention}

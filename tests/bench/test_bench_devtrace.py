@@ -24,6 +24,14 @@ PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 DECODE_POS = [np.array([20, 7, -1, -1]), np.array([21, 8, -1, -1])]
 
 
+def _mosaic(name: str, result: str, operands: list[str]) -> str:
+    """A Mosaic kernel's HLO instruction as the trace names it."""
+    args = ", ".join(f"{o} %x{i}" for i, o in enumerate(operands))
+    return (f'%{name} = {result}{{1,0}} custom-call({args}), '
+            f'custom_call_target="tpu_custom_call", '
+            f'operand_layout_constraints={{{", ".join(operands)}}}')
+
+
 @pytest.fixture(scope="module")
 def ev():
     return devtrace.events(ProfileData.from_text_proto(
@@ -97,6 +105,19 @@ def test_kernels_are_told_apart_by_their_instruction(ev):
     assert devtrace.parse_kernel(ev["ops"][5]["name"])["result"] == (4, 4, 16)
     assert devtrace.parse_kernel(ev["ops"][0]["name"]) is None
     assert devtrace.short_name(ev["ops"][0]["name"]) == "fusion"
+    # a kernel takes its kind from its instruction's name, whatever its shape
+    named = {name: devtrace.parse_kernel(_mosaic(name, result, operands))
+             for name, result, operands in (
+                 ("tt_linear.48", "bf16[8,64]", ["bf16[8,64]", "bf16[16,16]"]),
+                 ("paged_attention.3", "bf16[4,4,16]", ["s32[8]", "s32[4]", "bf16[4,4,16]"]),
+                 ("prefill_attention.12.1", "bf16[2,32,4,16]", ["s32[8]", "s32[2]", "bf16[2,32,4,16]"]),
+                 ("mla_decode.2", "bf16[8,64]", ["bf16[8,64]", "bf16[16,16]"]))}
+    assert {n: k["kind"] for n, k in named.items()} == {
+        "tt_linear.48": "tt_linear",
+        "paged_attention.3": "paged_attention",
+        "prefill_attention.12.1": "prefill_attention",
+        "mla_decode.2": "mla_decode"}  # its own kind, though shaped like a tt_linear
+    assert named["mla_decode.2"]["result"] == (8, 64)
 
 
 def test_roofline_is_least_time_over_device_time(red):
@@ -127,6 +148,75 @@ def test_useful_flops_leave_out_padding(red):
         ctx = [int(p) + 1 for p in pos if p >= 0]
         want += opcount.sequence_flops(MODEL, TT, ctx, len(ctx))
     assert flops == want
+
+
+MOE = {"family": "moe", "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
+       "head_dim": 16, "d_ff": 32, "n_experts": 8, "experts_per_token": 2,
+       "d_ff_expert": 32, "vocab_size": 512}
+
+
+def test_a_configurations_own_ops_module_counts_its_model(red, monkeypatch):
+    import model as bench_model
+    cj = bench_model.load_config(FIX / "tiny-moe.json")
+    ops = bench_model.ops_module(FIX / "tiny-moe.json", cj)
+    assert Path(ops.__file__).name == "tiny_moe_ops.py"
+    assert bench_model.ops_module(FIX / "tiny-tt.json",
+                                  bench_model.load_config(FIX / "tiny-tt.json")) is opcount
+
+    def dense_count(*a, **k):
+        raise AssertionError("the default module's dense count was used")
+
+    monkeypatch.setattr(opcount, "token_flops", dense_count)
+    flops = devtrace.useful_flops(red["execs"], MOE, {}, ops)
+    prompt = [i + 1 for i in range(20)] + [i + 1 for i in range(7)]
+    want = ops.sequence_flops(MOE, {}, prompt, 2)
+    for pos in DECODE_POS:
+        ctx = [int(p) + 1 for p in pos if p >= 0]
+        want += ops.sequence_flops(MOE, {}, ctx, len(ctx))
+    assert flops == want
+    # 2 routed experts of width 32 a token, not a dense d_ff
+    per_token = ops.sequence_flops(MOE, {}, [0], 0)
+    assert per_token == 2 * (2 * 64 * (64 + 2 * 32) + 2 * 64 * 64 + 2 * 64 * 8
+                             + 2 * 3 * 2 * 64 * 32)
+    # its kernels: the attention it runs, and no tt_linear
+    monkeypatch.setattr(opcount, "KERNELS", {})
+    share, _ = devtrace.roofline_of(red["kernel_events"], "paged_attention", {}, MOE,
+                                    PEAKS, ops=ops)
+    assert share > 0
+    assert devtrace.roofline_of(red["kernel_events"], "tt_linear", TT, MOE, PEAKS,
+                                ops=ops) is None
+
+
+def test_a_kernel_kind_counts_through_the_ops_table(red):
+    seen = []
+
+    class Ops:
+        KERNELS = {"tt_linear": lambda k, run: seen.append((k["result"], run)) or (1, 1)}
+
+    devtrace.roofline_of(red["kernel_events"], "tt_linear", TT, MODEL, PEAKS,
+                         ops=Ops, events=["e"])
+    assert [r for r, _ in seen] == [(64, 64), (4, 64), (4, 64)]
+    run = seen[0][1]
+    assert (run.model, run.tt, run.events) == (MODEL, TT, ["e"])
+
+
+def test_a_call_the_ops_table_cannot_count_leaves_the_share_unread(red):
+    # a share of the other calls would be biased: none is read, as an
+    # attention call without its pairing reads none
+    class Ops:
+        KERNELS = {"tt_linear": lambda k, run: None if k["result"][0] == 4 else (1, 1)}
+
+    assert devtrace.roofline_of(red["kernel_events"], "tt_linear", TT, MODEL, PEAKS,
+                                ops=Ops) is None
+    unpaired = [dict(k, call=None) if k["kind"] == "paged_attention" else k
+                for k in red["kernel_events"]]
+    assert devtrace.roofline_of(unpaired, "paged_attention", TT, MODEL, PEAKS) is None
+    assert devtrace.roofline_of(unpaired, "tt_linear", TT, MODEL, PEAKS) == \
+        devtrace.roofline_of(red["kernel_events"], "tt_linear", TT, MODEL, PEAKS)
+    # a tt_linear of no TT role cannot be counted either
+    assert devtrace.roofline_of(red["kernel_events"], "tt_linear",
+                                {"mlp_up": TT["attn_o"] | {"in_modes": (2, 4, 4)}},
+                                MODEL, PEAKS) is None
 
 
 def test_breakdown_names_ops_and_gaps(red, ev):

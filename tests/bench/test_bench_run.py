@@ -20,6 +20,7 @@ FIX = Path(__file__).resolve().parent / "fixtures"
 sys.path[:0] = [str(ROOT / "bench")]
 
 import harness  # noqa: E402
+import model as bench_model  # noqa: E402
 import peaks  # noqa: E402
 import serve as drivers  # noqa: E402
 from repro.serve.engine import Engine  # noqa: E402
@@ -39,11 +40,27 @@ def tiny_spec():
     return spec
 
 
-def run_tiny(cell, seconds=2.0, trace=False, **kw):
+def moe_spec():
+    """``tiny_spec()`` with a configuration of another shape added by files
+    alone, all under ``tests/bench/fixtures``: a mixture of experts with no
+    TT layers, its own reference (with a leaf rule), its own operation
+    counts, and a float32 mix."""
+    spec = tiny_spec()
+    spec["configs"].append({"name": "tiny-moe",
+                            "file": "tests/bench/fixtures/tiny-moe.json"})
+    spec["workloads"].append({"name": "tiny-moe.open", "config": "tiny-moe",
+                              "traffic": "tiny-f32", "chips": 1})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append("tiny-moe.open")
+    return spec
+
+
+def run_tiny(cell, seconds=2.0, trace=False, spec=None, **kw):
     kw = dict(dict(kernel_backend="pallas-interpret",
                    expect_backend="pallas-interpret", traffic_dir=FIX,
                    limits_dir=FIX), **kw)
-    return harness.run_cell(tiny_spec(), cell, 2**33 + 5, seconds, trace,
+    return harness.run_cell(spec or tiny_spec(), cell, 2**33 + 5, seconds, trace,
                             root=ROOT, t_start=time.perf_counter(), **kw)
 
 
@@ -67,6 +84,35 @@ def test_run_prints_a_result_line_that_parses(cell, metrics, capsys):
     err = capsys.readouterr().err.strip().splitlines()
     assert err[-len(c):] == [f"check {k} {v['value']} limit {v['limit']}"
                              for k, v in c.items()]
+
+
+def test_a_configuration_of_another_shape_enters_by_its_files_alone(monkeypatch):
+    cj = json.loads((FIX / "tiny-moe.json").read_text())
+    assert cj["model"]["family"] == "moe" and "ttd" not in cj["model"]
+    # every file the cell reads lies outside bench/
+    for f in (FIX / "tiny-moe.json", FIX / cj["reference"], FIX / cj["ops"],
+              FIX / "tiny-f32.json", FIX / "tiny-moe.open.json"):
+        assert f.is_file() and (ROOT / "bench") not in f.resolve().parents
+    seen = {}
+    real_served = bench_model.served_config
+
+    def served(cj, **kw):
+        seen["cfg"] = real_served(cj, **kw)
+        return seen["cfg"]
+
+    monkeypatch.setattr(bench_model, "served_config", served)
+    res = run_tiny("tiny-moe.open", spec=moe_spec(), with_control=True)
+    c = res["checks"]
+    assert res["correct"] is True, c
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert c["roles_not_pallas"]["value"] == 0
+    assert c["served_gap"]["value"] <= c["served_gap"]["limit"] < c["control_gap"]["value"]
+    assert res["control_correct"] is False
+    assert set(res["metrics"]) == {"ttft_p50_ms", "itl_p95_ms", "setup_s"}
+    cfg = seen["cfg"]
+    assert (cfg.family, cfg.n_experts, cfg.experts_per_token, cfg.d_ff_expert) == \
+        ("moe", 8, 2, 32)
+    assert not cfg.ttd.enabled
 
 
 def test_run_without_a_limit_is_not_correct(tmp_path):

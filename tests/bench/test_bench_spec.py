@@ -38,6 +38,19 @@ def test_every_cell_finds_its_files(cell):
     assert harness.cell_metrics(SPEC, cell["name"], "per_layer")
 
 
+@pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_configuration_finds_what_it_names(entry):
+    """The reference beside the file and its ops module (or the default)."""
+    import check
+    import model as bench_model
+    path = ROOT / entry["file"]
+    cj = bench_model.load_config(path)
+    assert callable(check.load_reference(path, cj).forward)
+    ops = bench_model.ops_module(path, cj)
+    assert callable(ops.sequence_flops) and isinstance(ops.KERNELS, dict)
+    bench_model.served_config(cj)
+
+
 @pytest.mark.parametrize("entry", SPEC["end_to_end"] + SPEC["per_layer"],
                          ids=lambda m: m["name"])
 def test_every_metric_has_a_reader_that_agrees(entry):
